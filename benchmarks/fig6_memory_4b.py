@@ -74,6 +74,9 @@ def main():
         print('RESULT ' + json.dumps(out))
     """ % (N, S, B))
     env = dict(os.environ)
+    # a fake-device HLO analysis: the child stays on the CPU, off any chip
+    # this process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = str(Path(__file__).resolve().parent.parent / "src") \
         + ":" + str(Path(__file__).resolve().parent.parent)

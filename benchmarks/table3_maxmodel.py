@@ -60,6 +60,9 @@ CODE = """
 
 def _peak(size, scheme):
     env = dict(os.environ)
+    # a fake-device HLO analysis: the child stays on the CPU, off any chip
+    # this process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     root = Path(__file__).resolve().parent.parent
     env["PYTHONPATH"] = f"{root/'src'}:{root}"

@@ -625,20 +625,11 @@ def mesh_capability(opt: "OptimizerConfig", mesh_shape: Tuple[int, ...],
                              the same size (the reduce-scatter ring order
                              is the linearized axis product either way).
       shardmap, tp_axis
-        size > 1           : manual-DP x auto-TP. Requires jax >= 0.6
-                             (jax.shard_map with axis_names=): the 0.4.x
-                             GSPMD partitioner cannot propagate manual
-                             subgroup shardings through the arena collect-
-                             ives ("Check failed: sharding.IsManualSubgroup"
-                             / PartitionId UNIMPLEMENTED). On older jax the
-                             refusal names the two escapes: make the tp
-                             axis manual (fold it into the dp product) or
-                             use the pjit engine. On jax >= 0.6
-                             master_params under mixed mode additionally
-                             refuses until the working-row all-gather
-                             learns a tp-subgroup layout.
+        size > 1           : manual-DP x auto-TP (jax.shard_map with
+                             axis_names=). master_params refuses until the
+                             working-row all-gather learns a tp-subgroup
+                             layout.
     """
-    import jax
     if len(mesh_shape) != len(mesh_axes):
         return (f"mesh_shape={mesh_shape} and mesh_axes={mesh_axes} "
                 f"disagree in rank; give one size per axis name")
@@ -654,14 +645,6 @@ def mesh_capability(opt: "OptimizerConfig", mesh_shape: Tuple[int, ...],
     tp = sizes.get(tp_axis, 1) if tp_axis is not None else 1
     if tp <= 1:
         return None                       # pure manual-DP product: supported
-    if not hasattr(jax, "shard_map"):
-        return (f"mixed manual-DP x auto-TP shard_map (tp_axis="
-                f"{tp_axis!r} of size {tp} left auto while the dp axes are "
-                f"manual) requires jax >= 0.6: the 0.4.x GSPMD partitioner "
-                f"aborts on manual-subgroup shardings through the arena "
-                f"collectives. Either fold {tp_axis!r} into the manual dp "
-                f"product (profile='dp' — bitwise equal to the flat dp "
-                f"mesh) or use engine='pjit'")
     if opt.master_params:
         return (f"master_params=True under mixed manual-DP x auto-TP "
                 f"(tp_axis={tp_axis!r} size {tp}) is unsupported: the "

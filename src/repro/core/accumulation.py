@@ -132,10 +132,12 @@ def _fold_decay(i, beta1: float, beta2: float, m_devices: int = 1):
 
 
 def _split_micro(batch: Dict[str, Any], n: int):
+    from repro.sharding.ctx import shard_micro_batches
+
     def r(x):
         gb = x.shape[0]
         assert gb % n == 0, f"global batch {gb} not divisible by micro {n}"
-        return x.reshape((n, gb // n) + x.shape[1:])
+        return shard_micro_batches(x.reshape((n, gb // n) + x.shape[1:]))
     return jax.tree.map(r, batch)
 
 

@@ -103,14 +103,14 @@ per-bucket psum_scatter and its reduction order are untouched.
 
 Manual axes = the DP axes ("data", and "pod" when multi-pod); the "model"
 axis (if present in the mesh) is left to GSPMD (auto) so tensor-parallel
-sharding composes — on jax >= 0.6 (jax.shard_map). The 0.4.x GSPMD
-partitioner aborts on manual-subgroup shardings through the arena
-collectives, so mixed manual-dp x auto-tp refuses there with the escape
-named (configs/base.py::mesh_capability): fold the tp axis into the
-manual dp product — a 2dp x 2tp ("data", "model") ALL-MANUAL mesh is
-bitwise identical to the flat 4-dp mesh, because the linearized axis
-product gives the same reduce-scatter ring order — or use the pjit
-engine. The linear dp rank used for owned-row indexing and fault
+sharding composes (jax.shard_map with axis_names=). Folding the tp axis
+into the manual dp product instead — a 2dp x 2tp ("data", "model")
+ALL-MANUAL mesh — is bitwise identical to the flat 4-dp mesh, because the
+linearized axis product gives the same reduce-scatter ring order. On a
+TPU the all-manual form is the one that compiles: XLA cannot partition a
+Mosaic kernel along an auto axis, so the arena kernels inside a mixed
+manual-dp x auto-tp shard_map run only in interpret mode (CPU). The
+linear dp rank used for owned-row indexing and fault
 targeting is an iota INPUT sharded over the dp axes (in_spec P(dp_axes)),
 not lax.axis_index: axis_index lowers to PartitionId, which GSPMD cannot
 partition inside a manual subgroup when auto axes remain.
@@ -137,18 +137,12 @@ from repro.optim import adam
 
 
 def _shard_map(f, mesh, *, in_specs, out_specs, manual_axes):
-    """shard_map across jax versions: `jax.shard_map(axis_names=...)` when
-    available (>= 0.6), else `jax.experimental.shard_map` with the
-    complementary `auto=` set (0.4.x). Replication checking is off either
-    way (psum-of-replicated patterns in the AdamA schedule trip it)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs,
-                             axis_names=set(manual_axes), check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    auto = frozenset(mesh.axis_names) - frozenset(manual_axes)
-    return shard_map(f, mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False, auto=auto)
+    """jax.shard_map manual over `manual_axes` only, the rest left auto.
+    Replication checking is off (psum-of-replicated patterns in the AdamA
+    schedule trip it)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs,
+                         axis_names=set(manual_axes), check_vma=False)
 
 
 def _ring_all_gather(x, axis_names, m: int, rank):

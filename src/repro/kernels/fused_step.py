@@ -58,6 +58,10 @@ Codec fragments (all fused in-pass, never a separate kernel):
 All fp32 operands are packed with a cast; betas/scale are static,
 step-dependent scalars ride in SMEM so one compiled kernel serves every step.
 
+`interpret=None` (every entry point's default) asks kernels/ops.py::
+_interpret: the kernels compile on TPU, run in Pallas interpret mode on the
+CPU backend, and raise on any other backend.
+
 Mixed-precision wire (grad_dtype): the gradient operand of fold/fold_slice
 may arrive as bf16 — the kernels upcast it to fp32 IN-PASS (one `.astype`
 on the VMEM block before the scale multiply), so the moments always
@@ -93,6 +97,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels.adama_accum import (BLOCK_ROWS, LANES, fac_row_stat,
                                        q8_decode_rows, q8_encode_rows,
@@ -344,6 +349,57 @@ def _split(out, nm):
     return tuple(out[:nm]), tuple(out[nm:])
 
 
+def _on_mesh(call, args, row_in, row_out, acc=()):
+    """Run `call(*args)` — one pallas_call over arena rows — under the pjit
+    engine's mesh (sharding/ctx.py::kernel_mesh). XLA cannot partition a
+    Mosaic kernel, so the call goes into a shard_map manual over every
+    mesh axis, and each device runs the kernel on its own rows: operands
+    flagged in `row_in` enter as their row block over the dp axes, the
+    rest whole; `row_out` flags the results likewise. `acc` pairs each
+    replicated accumulator result with its aliased operand (rowcol's column
+    sums): every device folds its rows into zeros and the partials are
+    psum'd onto the operand. Rows that do not split into block-aligned dp
+    shards — and `row_in=()` — run the whole kernel on every device.
+    Without such a mesh `call` runs as is."""
+    from repro.core.arena import ROW_ALIGN
+    from repro.sharding.ctx import kernel_mesh
+    km = kernel_mesh()
+    if km is None:
+        return call(*args)
+    mesh, dp = km
+    n = 1
+    for a in dp:
+        n *= mesh.shape[a]
+    rows = next((x.shape[0] for x, r in zip(args, row_in) if r), 0)
+    per = rows // n if dp and rows % n == 0 else 0
+    split = per > 0 and per % ROW_ALIGN == 0 and (
+        per <= BLOCK_ROWS or per % BLOCK_ROWS == 0)
+    row = P(dp, None) if split else P()
+
+    def body(*xs):
+        local = list(xs)
+        if split:
+            for _, i in acc:
+                local[i] = jnp.zeros_like(xs[i])
+        out = call(*local)
+        if not isinstance(out, (tuple, list)):
+            return out
+        out = list(out)
+        if split:
+            for o, i in acc:
+                out[o] = xs[i] + jax.lax.psum(out[o], dp)
+        return tuple(out)
+
+    in_specs = tuple(row if r else P() for r in row_in) \
+        + (P(),) * (len(args) - len(row_in))
+    if isinstance(row_out, (tuple, list)):
+        out_specs = tuple(row if r else P() for r in row_out)
+    else:
+        out_specs = row if row_out else P()
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)(*args)
+
+
 # ---------------------------------------------------------------------------
 # The three builder-generated entry points
 # ---------------------------------------------------------------------------
@@ -433,24 +489,33 @@ def arena_fold(m, v, g, *, beta1: float, beta2: float, scale=1.0,
     nm, nv = len(m_parts), len(v_parts)
     flag, body_scale, scalars_fn, guarded = _fold_guard_setup(g, scale, guard)
     n_sc = 2 if body_scale is not None else 4
-    gspec = pl.BlockSpec((block, LANES), lambda i: (i, 0))
     has_gs = grad_scale is not None
     gs_in = [grad_scale] if has_gs else []
-    gs_specs = [pl.BlockSpec((block, 1), lambda i: (i, 0))] if has_gs else []
-    out = pl.pallas_call(
-        functools.partial(_fold_body, mk=mk, vk=vk, beta1=beta1, beta2=beta2,
-                          scale=body_scale, nm=nm, nv=nv, guarded=guarded,
-                          has_gs=has_gs),
-        grid=(rows // block,),
-        in_specs=[pl.BlockSpec((n_sc,), lambda i: (0,))]
-        + _col_specs(mk.cols, block, sliced=False)
-        + _col_specs(vk.cols, block, sliced=False) + [gspec] + gs_specs,
-        out_specs=_col_specs(mk.cols, block, sliced=False)
-        + _col_specs(vk.cols, block, sliced=False),
-        out_shape=_out_shapes(m_parts + v_parts),
-        input_output_aliases={1 + k: k for k in range(nm + nv)},
-        interpret=_interpret() if interpret is None else interpret,
-    )(scalars_fn(decay), *m_parts, *v_parts, g, *gs_in)
+
+    def call(sc, *xs):              # xs: the (per-device) state + slab rows
+        block = min(BLOCK_ROWS, xs[nm + nv].shape[0])
+        gspec = pl.BlockSpec((block, LANES), lambda i: (i, 0))
+        gs_specs = [pl.BlockSpec((block, 1), lambda i: (i, 0))] * has_gs
+        return pl.pallas_call(
+            functools.partial(_fold_body, mk=mk, vk=vk, beta1=beta1,
+                              beta2=beta2, scale=body_scale, nm=nm, nv=nv,
+                              guarded=guarded, has_gs=has_gs),
+            grid=(xs[nm + nv].shape[0] // block,),
+            in_specs=[pl.BlockSpec((n_sc,), lambda i: (0,))]
+            + _col_specs(mk.cols, block, sliced=False)
+            + _col_specs(vk.cols, block, sliced=False) + [gspec] + gs_specs,
+            out_specs=_col_specs(mk.cols, block, sliced=False)
+            + _col_specs(vk.cols, block, sliced=False),
+            out_shape=_out_shapes(xs[:nm + nv]),
+            input_output_aliases={1 + k: k for k in range(nm + nv)},
+            interpret=_interpret() if interpret is None else interpret,
+        )(sc, *xs)
+
+    row_cols = [c.row_indexed for c in mk.cols + vk.cols]
+    out = _on_mesh(call, (scalars_fn(decay), *m_parts, *v_parts, g, *gs_in),
+                   row_in=(False, *row_cols, True, *[True] * has_gs),
+                   row_out=row_cols,
+                   acc=[(k, 1 + k) for k, r in enumerate(row_cols) if not r])
     m_new, v_new = _split(out, nm)
     m_new = m_new[0] if m_bare else m_new
     v_new = v_new[0] if v_bare else v_new
@@ -507,7 +572,7 @@ def arena_fold_slice(m, v, g, row_offset, *, beta1: float, beta2: float,
         + _col_specs(vk.cols, block, sliced=True),
     )
     off = jnp.asarray(row_offset, jnp.int32).reshape(1) // block
-    out = pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_slice_fold_body, mk=mk, vk=vk, beta1=beta1,
                           beta2=beta2, scale=body_scale, nm=nm, nv=nv,
                           guarded=guarded, has_gs=has_gs),
@@ -515,7 +580,12 @@ def arena_fold_slice(m, v, g, row_offset, *, beta1: float, beta2: float,
         out_shape=_out_shapes(m_parts + v_parts),
         input_output_aliases={2 + k: k for k in range(nm + nv)},
         interpret=_interpret() if interpret is None else interpret,
-    )(off, scalars_fn(decay), *m_parts, *v_parts, g, *gs_in)
+    )
+    # the offset addresses global arena rows, so under a pjit mesh every
+    # device folds the whole slice into the whole arena (the shard_map
+    # ZeRO-1 engine is this path's row-sharded form)
+    out = _on_mesh(call, (off, scalars_fn(decay), *m_parts, *v_parts, g,
+                          *gs_in), row_in=(), row_out=[False] * (nm + nv))
     m_new, v_new = _split(out, nm)
     m_new = m_new[0] if m_bare else m_new
     v_new = v_new[0] if v_bare else v_new
@@ -583,28 +653,35 @@ def arena_apply(p, m, v, *, lr, bc1, bc2, eps: float = 1e-8,
     if guarded:
         sc.append(jnp.asarray(guard, jnp.float32))
     scalars = jnp.stack(sc)
-    pspec = pl.BlockSpec((block, LANES), lambda i: (i, 0))
     emit_work = work_dtype is not None
     if emit_work:
         assert p.dtype == jnp.float32, \
             f"master-param apply requires an fp32 master region, got {p.dtype}"
-        out_specs = [pspec, pspec]
-        out_shape = [jax.ShapeDtypeStruct(p.shape, p.dtype),
-                     jax.ShapeDtypeStruct(p.shape, jnp.dtype(work_dtype))]
-    else:
-        out_specs = pspec
+
+    def call(sc, p, *mv):           # p, mv: the (per-device) arena rows
+        block = min(BLOCK_ROWS, p.shape[0])
+        pspec = pl.BlockSpec((block, LANES), lambda i: (i, 0))
         out_shape = jax.ShapeDtypeStruct(p.shape, p.dtype)
-    out = pl.pallas_call(
-        functools.partial(_apply_body, mk=mk, vk=vk, eps=eps,
-                          weight_decay=weight_decay, nm=nm, nv=nv,
-                          emit_work=emit_work, guarded=guarded),
-        grid=(rows // block,),
-        in_specs=[pl.BlockSpec((4 if guarded else 3,), lambda i: (0,)), pspec]
-        + _col_specs(mk.cols, block, sliced=False)
-        + _col_specs(vk.cols, block, sliced=False),
-        out_specs=out_specs,
-        out_shape=out_shape,
-        input_output_aliases={1: 0},
-        interpret=_interpret() if interpret is None else interpret,
-    )(scalars, p, *m_parts, *v_parts)
+        if emit_work:
+            out_shape = [out_shape,
+                         jax.ShapeDtypeStruct(p.shape, jnp.dtype(work_dtype))]
+        return pl.pallas_call(
+            functools.partial(_apply_body, mk=mk, vk=vk, eps=eps,
+                              weight_decay=weight_decay, nm=nm, nv=nv,
+                              emit_work=emit_work, guarded=guarded),
+            grid=(p.shape[0] // block,),
+            in_specs=[pl.BlockSpec((4 if guarded else 3,), lambda i: (0,)),
+                      pspec]
+            + _col_specs(mk.cols, block, sliced=False)
+            + _col_specs(vk.cols, block, sliced=False),
+            out_specs=[pspec, pspec] if emit_work else pspec,
+            out_shape=out_shape,
+            input_output_aliases={1: 0},
+            interpret=_interpret() if interpret is None else interpret,
+        )(sc, p, *mv)
+
+    out = _on_mesh(call, (scalars, p, *m_parts, *v_parts),
+                   row_in=(False, True,
+                           *[c.row_indexed for c in mk.cols + vk.cols]),
+                   row_out=[True, True] if emit_work else True)
     return tuple(out) if emit_work else out

@@ -1,5 +1,6 @@
 """jit-ready wrappers: flatten pytree leaves to hardware-aligned 2D tiles and
-dispatch the Pallas kernels (interpret=True on CPU, compiled on TPU)."""
+dispatch the Pallas kernels (interpret=True on CPU, compiled on TPU; any
+other backend raises, see `_interpret`)."""
 from __future__ import annotations
 
 import functools
@@ -12,7 +13,18 @@ from repro.kernels.adama_accum import LANES, adama_accum_2d
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Whether the Pallas kernels run in interpret mode: True on the `cpu`
+    backend (tests, CPU runs), False on `tpu`, where they compile to
+    Mosaic. Any other backend raises instead of quietly interpreting the
+    kernels on a device they were not written for."""
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(
+        f"the Pallas kernels compile for TPU and interpret on CPU only; "
+        f"the default backend is {backend!r}")
 
 
 def _to_2d(x):

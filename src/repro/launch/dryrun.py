@@ -322,8 +322,6 @@ def run_one(arch, shape_name, multi_pod, outdir, **kw):
     t_compile = time.time() - t0 - t_lower
     ma = compiled.memory_analysis()
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):       # jax<=0.4.x: one dict per program
-        ca = ca[0] if ca else {}
     txt = compiled.as_text()
     hlo = analyze_hlo(txt)
     coll = {k[5:]: v for k, v in hlo.items() if k.startswith("coll_")}

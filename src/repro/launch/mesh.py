@@ -11,23 +11,15 @@ Defined as functions so importing this module never touches jax device state.
 from __future__ import annotations
 
 import jax
-
-try:                              # jax >= 0.5: explicit Auto/Manual axis types
-    from jax.sharding import AxisType
-except ImportError:               # jax 0.4.x: all mesh axes are Auto already
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def make_mesh(shape, axes, devices=None):
-    """jax.make_mesh across jax versions: passes axis_types=(Auto, ...) when
-    the running jax supports it (0.4.x has no axis_types kwarg and treats
-    every axis as Auto, which is exactly what we want)."""
-    kw = {}
-    if devices is not None:
-        kw["devices"] = devices
-    if AxisType is not None:
-        kw["axis_types"] = (AxisType.Auto,) * len(shape)
-    return jax.make_mesh(tuple(shape), tuple(axes), **kw)
+    """jax.make_mesh with every axis Auto (GSPMD propagates shardings; the
+    shard_map engines mark their own axes manual)."""
+    kw = {} if devices is None else {"devices": devices}
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(shape), **kw)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

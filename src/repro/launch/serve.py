@@ -37,6 +37,7 @@ from repro.configs import get_config
 from repro.configs.base import InputShape, ModelConfig
 from repro.core import kv_arena
 from repro.data import make_data
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import decode as dec
 from repro.models.model import init_params
 
@@ -343,6 +344,7 @@ def main():
     ap.add_argument("--ckpt-wp", action="store_true",
                     help="checkpoint carries a work_param_cache region")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -1,7 +1,9 @@
 """Training launcher.
 
-CPU-scale runs execute for real; production shapes are launched via
---dry-run (see launch/dryrun.py for the mesh proof).
+Runs on whatever backend JAX finds: a TPU at published widths (bert_large
+at global batch 4 x seq 512 fits one v5e chip; see chip_smoke.py), or the
+CPU with `--reduced` widths and the Pallas kernels in interpret mode.
+`launch/dryrun.py` compiles production meshes without running them.
 
   PYTHONPATH=src python -m repro.launch.train --arch stablelm-1.6b --reduced \
       --steps 50 --accumulation adama --micro-batches 4
@@ -9,17 +11,15 @@ CPU-scale runs execute for real; production shapes are launched via
 from __future__ import annotations
 
 import argparse
-import dataclasses
 
-import jax
-
-from repro.configs import INPUT_SHAPES, InputShape, OptimizerConfig, RunConfig, get_config
+from repro.configs import InputShape, OptimizerConfig, RunConfig, get_config
 from repro.configs.base import GRAD_DTYPES, M_CODECS, STATE_CODECS
+from repro.launch.compile_cache import use_compile_cache
 from repro.optim import schedule as sched
 from repro.train.loop import train
 
 
-def main():
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true",
@@ -110,8 +110,11 @@ def main():
                          "crash@step=3")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=10)
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+def build_run(args: argparse.Namespace):
+    """(RunConfig, lr schedule) for parsed CLI arguments."""
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
@@ -138,7 +141,12 @@ def main():
         log_every=args.log_every, checkpoint_dir=args.checkpoint_dir,
         checkpoint_every=args.checkpoint_every,
         keep_last_n=args.keep_last_n, inject_fault=args.inject_fault)
-    lr_fn = sched.warmup_cosine(args.lr, args.warmup, args.steps)
+    return run, sched.warmup_cosine(args.lr, args.warmup, args.steps)
+
+
+def main(argv=None):
+    run, lr_fn = build_run(parse_args(argv))
+    use_compile_cache()
     out = train(run, lr_schedule=lr_fn)
     print(f"[train] done; final loss {out['losses'][-1]:.4f} "
           f"(first {out['losses'][0]:.4f})")

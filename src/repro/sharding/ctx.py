@@ -52,8 +52,41 @@ def dp_axes() -> Tuple[str, ...]:
     return _DP.get()
 
 
+def kernel_mesh():
+    """(mesh, dp axes) when a multi-device mesh is installed and no
+    surrounding shard_map holds any of its axes manual — the pjit engine's
+    regime, in which XLA would have to partition a Mosaic kernel and cannot
+    (kernels/fused_step.py shard_maps its kernels over this mesh) — else
+    None."""
+    mesh = _MESH.get()
+    if mesh is None or mesh.size == 1 or _MANUAL.get():
+        return None
+    return mesh, _DP.get()
+
+
 def tp_axis() -> Optional[str]:
     return _TP.get()
+
+
+def _dp_if_divides(n: int):
+    """The installed dp axes when a batch dim of size `n` splits evenly
+    over them, else None (replicated)."""
+    mesh, dp = _MESH.get(), _DP.get()
+    if mesh is None or not dp:
+        return None
+    size = 1
+    for a in dp:
+        size *= mesh.shape[a]
+    return dp if n % size == 0 else None
+
+
+def shard_micro_batches(x):
+    """Pin (N, B/N, ...) micro-batches: each micro-batch's rows over dp
+    (when they divide), the micro-batch axis unsharded, so every step of
+    the scan over micro-batches finds its rows already split."""
+    if _MESH.get() is None:
+        return x
+    return maybe_shard(x, None, _dp_if_divides(x.shape[1]))
 
 
 def shard_attention_operand(x):
@@ -67,9 +100,7 @@ def shard_attention_operand(x):
         return x
     tp = mesh.shape.get("model", 1)
     dp = _DP.get()
-    import numpy as np
-    dpsz = int(np.prod([mesh.shape[a] for a in dp])) if dp else 1
-    b_ax = dp if (dp and x.shape[0] % max(dpsz, 1) == 0) else None
+    b_ax = _dp_if_divides(x.shape[0])
     h_ax = "model" if (tp > 1 and x.shape[1] % tp == 0 and
                        "model" not in (dp or ())) else None
     return maybe_shard(x, b_ax, h_ax, None, None)

@@ -44,9 +44,9 @@ class Rules:
     over `tp_axis` only. FSDP is disabled (the manual schedule owns the dp
     dimension of the state; double-sharding d_model over dp would fight
     it), `dp_axes()` excludes the tp axis, and batch shards over dp only.
-    Gated by configs/base.py::mesh_capability — on jax < 0.6 the mixed
-    regime is refused and the escape is folding tp into the manual dp
-    product (profile="dp" on the same 2D mesh, bitwise-equal to flat dp).
+    Gated by configs/base.py::mesh_capability. Folding tp into the manual
+    dp product instead is profile="dp" on the same 2D mesh, bitwise-equal
+    to flat dp.
     """
 
     def __init__(self, cfg: ModelConfig, mesh, *, tp_axis="model",

@@ -11,24 +11,46 @@ micro-batches skip (a run that is only skipping is not training)."""
 from __future__ import annotations
 
 import contextlib
+import functools
 import time
-from typing import Any, Callable, Dict, Optional
+from typing import Any, Dict
 
 import jax
-import jax.numpy as jnp
-import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import RunConfig
-from repro.core.accumulation import make_train_step
+from repro.core.accumulation import _zero_constrain, make_train_step
 from repro.data import make_data
 from repro.models.model import init_params
-from repro.optim import schedule as sched
 from repro.train import checkpoint as ckpt
 from repro.train import faults as faults_mod
 
 
+def zero1_shardings(mesh, opt, params, opt_state):
+    """Shardings of the ZeRO-1 step on the data mesh, as (params, opt
+    state, batch, metrics): params replicated, the arena state laid out
+    exactly as the step's own `_zero_constrain` lays out its outputs
+    (row-indexed columns row-sharded over "data", the rest replicated),
+    each batch split over "data". Pinning the step's in- and out-shardings
+    to these keeps step 1 and step 2 one compiled program. `params` and
+    `opt_state` may be arrays or shapes; the sharding ctx must hold
+    `mesh`."""
+    rep = NamedSharding(mesh, P())
+    o_sh = jax.jit(functools.partial(_zero_constrain, opt)).lower(
+        jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                                    sharding=rep),
+                     opt_state)).compile().output_shardings
+    return (jax.tree.map(lambda _: rep, params), o_sh,
+            NamedSharding(mesh, P("data")), rep)
+
+
 def train(run: RunConfig, *, lr_schedule=None, log_fn=print,
           params=None, data=None) -> Dict[str, Any]:
+    """Run `run.steps` training steps. Returns the final params and
+    optimizer state, the per-step losses, the last step's metrics,
+    `compile_s` (seconds to lower and compile the step, kept out of every
+    step time) and `compiled` (that compiled step, or None when no step
+    was left to run)."""
     cfg = run.model
     key = jax.random.key(run.seed)
     if params is None:
@@ -39,6 +61,7 @@ def train(run: RunConfig, *, lr_schedule=None, log_fn=print,
     # schedule via _zero_constrain. One device: plain unsharded step.
     opt = run.optimizer
     state_shards = 1
+    mesh = None
     mesh_ctx = contextlib.ExitStack()
     if opt.zero_stage == 1 and opt.arena and jax.device_count() > 1:
         from repro.launch.mesh import make_mesh
@@ -46,8 +69,8 @@ def train(run: RunConfig, *, lr_schedule=None, log_fn=print,
         state_shards = jax.device_count()
         # size-1 "model" axis so the models' activation constraints (which
         # name it) resolve on this data-only mesh
-        mesh_ctx.enter_context(shard_ctx.use_mesh(
-            make_mesh((state_shards, 1), ("data", "model")), ("data",)))
+        mesh = make_mesh((state_shards, 1), ("data", "model"))
+        mesh_ctx.enter_context(shard_ctx.use_mesh(mesh, ("data",)))
     elif opt.zero_stage == 1 and jax.device_count() > 1:
         log_fn("[train] note: zero_stage=1 without arena=True is a no-op in "
                "this single-process loop (only the arena row-range path is "
@@ -73,12 +96,30 @@ def train(run: RunConfig, *, lr_schedule=None, log_fn=print,
     if data is None:
         data = make_data(cfg, run.shape, seed=run.seed)
     every = run.checkpoint_every or max(run.log_every * 5, 50)
-    jstep = jax.jit(step_fn, donate_argnums=(0, 1))
     losses = []
-    t0 = time.time()
+    compiled, compile_s = None, 0.0
     with mesh_ctx:                  # row-range sharding ctx (no-op if empty)
+        batch_sharding, jit_kw = None, {}
+        if mesh is not None:
+            p_sh, o_sh, batch_sharding, rep = zero1_shardings(
+                mesh, opt, params, opt_state)
+            params, opt_state = jax.device_put((params, opt_state),
+                                               (p_sh, o_sh))
+            jit_kw = dict(in_shardings=(p_sh, o_sh, batch_sharding),
+                          out_shardings=(p_sh, o_sh, rep))
+        jstep = jax.jit(step_fn, donate_argnums=(0, 1), **jit_kw)
+        if start < run.steps:
+            # compile ahead of step 1 so no step time includes it; the jit
+            # call below reuses this executable
+            t0 = time.perf_counter()
+            compiled = jstep.lower(
+                params, opt_state,
+                jax.device_put(data.batch(start), batch_sharding)).compile()
+            compile_s = time.perf_counter() - t0
+            log_fn(f"[train] step compiled in {compile_s:.2f}s")
+        t0 = time.time()
         for i in range(start, run.steps):
-            batch = {k: jnp.asarray(v) for k, v in data.batch(i).items()}
+            batch = jax.device_put(data.batch(i), batch_sharding)
             params, opt_state, metrics = jstep(params, opt_state, batch)
             losses.append(float(metrics["loss"]))
             consec = int(metrics.get("consec_skips", 0))
@@ -114,4 +155,5 @@ def train(run: RunConfig, *, lr_schedule=None, log_fn=print,
                   keep=run.keep_last_n)
     return {"params": params, "opt_state": opt_state, "losses": losses,
             "metrics": {k: float(v) for k, v in metrics.items()}
-            if run.steps > start else {}}
+            if run.steps > start else {},
+            "compile_s": compile_s, "compiled": compiled}

@@ -311,21 +311,12 @@ def test_mesh_pjit_engine_accepts_any_tp():
 
 
 def test_mesh_mixed_auto_tp_gated_on_jax_version():
-    import jax
     reason = mesh_capability(_good_opt(), (2, 2), ("data", "model"),
                              tp_axis="model", engine="shardmap")
-    if not hasattr(jax, "shard_map"):
-        # jax < 0.6: refusal must name BOTH escapes
-        assert "jax >= 0.6" in reason
-        assert "manual dp product" in reason and "pjit" in reason
-    else:
-        assert reason is None
+    assert reason is None
 
 
 def test_mesh_mixed_auto_tp_refuses_master_params_on_any_jax():
-    import jax
-    if not hasattr(jax, "shard_map"):
-        pytest.skip("jax < 0.6: mixed mode refuses earlier, on the version")
     reason = mesh_capability(_good_opt(master_params=True), (2, 2),
                              ("data", "model"), tp_axis="model")
     assert "master_params" in reason
@@ -353,3 +344,27 @@ def test_mesh_matrix_exhaustive_never_crashes():
                       master_params=master),
             shape, axes, tp_axis=tp, engine=engine)
         assert reason is None or isinstance(reason, str)
+
+
+def test_compile_cache_keeps_env_dir_else_fixed_checkout_path(monkeypatch,
+                                                              tmp_path):
+    """launch/compile_cache.py: JAX_COMPILATION_CACHE_DIR wins and nothing
+    else is set; without it the cache goes to <checkout>/.jax_cache, a path
+    that is the same on every run."""
+    from pathlib import Path
+
+    import jax
+    from repro.launch import compile_cache as cc
+    was = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert cc.use_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == was
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    checkout = Path(__file__).resolve().parents[1]
+    try:
+        assert cc.use_compile_cache() == str(checkout / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == str(
+            checkout / ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+    assert ".jax_cache/" in (checkout / ".gitignore").read_text().split()

@@ -592,14 +592,41 @@ def test_dryrun_lowers_on_small_mesh():
     """, devices=8)
 
 
+def test_pjit_zero1_loop_runs_arena_kernels_per_row_shard():
+    """The ZeRO-1 training loop (--zero-stage 1 --arena) on a 4-device data
+    mesh runs each arena kernel per row shard inside a shard_map (a TPU
+    cannot partition a Mosaic kernel): m/v stay 1/4 per device, and the
+    losses match zero_stage=0 on one device — for fp32 state and for
+    rowcol, whose replicated column sums are folded per shard and psum'd."""
+    out = run_sub("""
+        import dataclasses, jax, numpy as np
+        from repro.configs import get_config, OptimizerConfig, RunConfig
+        from repro.configs.base import InputShape
+        from repro.train.loop import train
+        cfg = dataclasses.replace(get_config('bert_large').reduced(),
+                                  compute_dtype='float32')
+        for codec in ('fp32', 'rowcol'):
+            def run(zero_stage):
+                opt = OptimizerConfig(name='adama', accumulation='adama',
+                                      micro_batches=2, use_pallas=True,
+                                      arena=True, state_codec=codec,
+                                      zero_stage=zero_stage)
+                return train(RunConfig(model=cfg, optimizer=opt,
+                                       shape=InputShape('t', 32, 8, 'train'),
+                                       steps=3, log_every=100),
+                             log_fn=lambda *_: None)
+            z0, z1 = run(0), run(1)
+            np.testing.assert_allclose(z1['losses'], z0['losses'], rtol=2e-5)
+            m = z1['opt_state']['m'].data
+            assert [s.data.shape[0] for s in m.addressable_shards] == \\
+                [m.shape[0] // 4] * 4
+            print(codec, 'LOSSES', z0['losses'], z1['losses'])
+        print('OK')
+    """, devices=4)
+    assert out.strip().endswith("OK")
+
+
 def test_shardmap_engine_lowers():
-    import jax
-    if not hasattr(jax, "shard_map"):
-        # partial-auto shard_map (manual DP axes + auto model axis for TP)
-        # fatally crashes old GSPMD: "Check failed: sharding.IsManualSubgroup"
-        # in hlo_sharding_util.cc. Pure-DP shard_map (the other three tests)
-        # works on 0.4.x via the auto= compat path in core/dp_shardmap.py.
-        pytest.skip("mixed manual/auto shard_map needs jax >= 0.6")
     run_sub("""
         import jax
         from repro.launch.mesh import make_mesh
@@ -804,8 +831,8 @@ def test_dp_zero1_async_pipeline_bitwise_matches_serial():
 
 def test_dp2_tp2_manual_product_matches_flat_4dp():
     """Mesh composition acceptance: a (2, 2) 'data' x 'model' mesh with
-    BOTH axes in the manual dp product (the supported composition on this
-    jax — mesh_capability gates true auto-TP behind jax >= 0.6) is BITWISE
+    BOTH axes in the manual dp product (the composition whose arena
+    kernels also compile on a TPU) is BITWISE
     identical to the flat 4-device dp mesh, async schedule included: the
     reduce-scatter ring order is the linearized axis product either way,
     and the ring all-gather's ppermute takes the same tuple of axis

@@ -98,3 +98,16 @@ def test_kernels_jit_and_grad_free():
     mo, vo = jax.jit(lambda m, v, g: ops.adama_accumulate(
         m, v, g, beta1=0.9, beta2=0.999))(m, v, g)
     assert mo.shape == (128, 64) and bool(jnp.all(vo >= 0))
+
+
+@pytest.mark.parametrize("backend,interpret", [("cpu", True), ("tpu", False),
+                                               ("gpu", None)])
+def test_interpret_mode_follows_backend(monkeypatch, backend, interpret):
+    """Interpret on the CPU, compile on the TPU, and refuse any other backend
+    rather than quietly interpreting the kernels there."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="'gpu'"):
+            ops._interpret()
+    else:
+        assert ops._interpret() is interpret

@@ -90,7 +90,7 @@ def test_zero1_adds_data_axis():
 
 
 def _abstract_mesh(shape):
-    return jax.sharding.AbstractMesh(tuple(shape.items()))
+    return jax.sharding.AbstractMesh(tuple(shape.values()), tuple(shape))
 
 
 def _zero1(mesh, psh, aparams):
