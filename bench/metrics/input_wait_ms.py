@@ -1,0 +1,10 @@
+"""input_wait_ms (ms): device idle time per step of the window that
+overlaps the loop's `train.batch` spans (the batch built and placed),
+interval by interval, with each chip's clock first aligned to the
+host's; mean over chips (layer: host loop, train/loop.py;
+bench/benchkit/scopes.py)."""
+from benchkit import scopes
+
+
+def read(ctx):
+    return scopes.wait_ms(ctx, scopes.INPUT_SPANS)

@@ -366,6 +366,17 @@ ZERO_STAGES = (0, 1)
 ACCUM_ENGINES = ("ga", "adama", "adama_layerwise")
 GRAD_DTYPES = ("fp32", "bf16", "fp8_e4m3")               # gradient wire
 
+# Names of the training step's phases (`jax.named_scope`). They reach each
+# compiled instruction's `metadata={op_name=...}`: the model's forward under
+# MODEL_SCOPE, its backward under `transpose(jvp(model))`, and the optimizer
+# phases under their own names, so a device trace can be split by phase.
+MODEL_SCOPE = "model"
+RECOMPUTE_SCOPE = "model.recompute"    # forward redone inside a backward
+GRAD_PACK_SCOPE = "optimizer.grad_pack"      # gradient tree -> arena slab
+FOLD_SCOPE = "optimizer.fold"                # slab -> (m, v)
+ACCUMULATE_SCOPE = "optimizer.accumulate"    # ga's gradient accumulator
+APPLY_SCOPE = "optimizer.apply"              # (m, v) -> params
+
 
 def grad_wire_dtype(name: str):
     """The jnp dtype a `grad_dtype` config value packs/moves gradients in —

@@ -39,7 +39,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.configs.base import ModelConfig, OptimizerConfig
+from repro.configs.base import (ACCUMULATE_SCOPE, APPLY_SCOPE,
+                                GRAD_PACK_SCOPE, ModelConfig,
+                                OptimizerConfig)
 from repro.core import adama
 from repro.core import arena as arena_mod
 from repro.models.model import loss_fn as model_loss_fn
@@ -176,10 +178,14 @@ def make_ga_step(cfg: ModelConfig, opt: OptimizerConfig, *, remat=False,
             g = fault_mod.corrupt_tree(fault, g, micro=i,
                                        step=opt_state["step"])
             if use_arena:
-                acc = acc + arena_mod.pack(g, layout) / n
+                with jax.named_scope(GRAD_PACK_SCOPE):
+                    slab = arena_mod.pack(g, layout)
+                with jax.named_scope(ACCUMULATE_SCOPE):
+                    acc = acc + slab / n
             else:
-                acc = jax.tree.map(
-                    lambda a, gg: a + gg.astype(jnp.float32) / n, acc, g)
+                with jax.named_scope(ACCUMULATE_SCOPE):
+                    acc = jax.tree.map(
+                        lambda a, gg: a + gg.astype(jnp.float32) / n, acc, g)
             return (acc, lsum + l), None
 
         zeros = (jnp.zeros((layout.rows, arena_mod.LANES), jnp.float32)
@@ -220,17 +226,18 @@ def make_ga_step(cfg: ModelConfig, opt: OptimizerConfig, *, remat=False,
                     growth_interval=opt.scaler_growth_interval))
             kw = dict(lr=lr, bc1=1 - opt.beta1 ** t, bc2=1 - opt.beta2 ** t,
                       eps=opt.eps, weight_decay=opt.weight_decay, guard=ok)
-            if state_store.has_master(opt_state):
-                work, opt_state = state_store.apply_master_state(
-                    opt_state, **kw)
-                if "wp" in opt_state:
-                    opt_state = dict(opt_state, wp=opt_state["wp"]
-                                     .with_data(work))
-                params = arena_mod.unpack(work, layout)
-            else:
-                p_new = state_store.apply_state(
-                    arena_mod.pack(params, layout), opt_state, **kw)
-                params = arena_mod.unpack(p_new, layout)
+            with jax.named_scope(APPLY_SCOPE):
+                if state_store.has_master(opt_state):
+                    work, opt_state = state_store.apply_master_state(
+                        opt_state, **kw)
+                    if "wp" in opt_state:
+                        opt_state = dict(opt_state, wp=opt_state["wp"]
+                                         .with_data(work))
+                    params = arena_mod.unpack(work, layout)
+                else:
+                    p_new = state_store.apply_state(
+                        arena_mod.pack(params, layout), opt_state, **kw)
+                    params = arena_mod.unpack(p_new, layout)
             metrics = {"loss": lsum / n}
             if ok is not None:
                 from repro.train.scaler import scaler_metrics
@@ -239,7 +246,9 @@ def make_ga_step(cfg: ModelConfig, opt: OptimizerConfig, *, remat=False,
         kw = dict(lr=lr, weight_decay=opt.weight_decay)
         if opt_mod is adam:
             kw.update(beta1=opt.beta1, beta2=opt.beta2, eps=opt.eps)
-        params, opt_state = opt_mod.update(grads, opt_state, params, **kw)
+        with jax.named_scope(APPLY_SCOPE):
+            params, opt_state = opt_mod.update(grads, opt_state, params,
+                                               **kw)
         return params, opt_state, {"loss": lsum / n}
 
     def init(params):
@@ -307,18 +316,20 @@ def make_adama_step(cfg: ModelConfig, opt: OptimizerConfig, *, remat=False,
                     lambda p: scaler_mod.scale_loss(loss(p, mb), sc))(params)
                 g = fault_mod.corrupt_tree(fault, g, micro=i,
                                            step=st["step"])
-                if fp8:
-                    # fp8 wire: pack fp32, inject the error-feedback
-                    # residual (stored UNSCALED — the dynamic loss scale
-                    # can change between micro-batches, so the S-scaled
-                    # slab gets ef*S), then encode codes + per-row scale.
-                    # Gradients arrive pre-reduced in the pjit engine, so
-                    # the encode needs no summation headroom (n_summands=1)
-                    slab = arena_mod.pack(g, layout, dtype=jnp.float32)
-                    if use_ef:
-                        slab = slab + st["ef"].data * sc["scale"]
-                else:
-                    slab = arena_mod.pack(g, layout, dtype=wire)
+                with jax.named_scope(GRAD_PACK_SCOPE):
+                    if fp8:
+                        # fp8 wire: pack fp32, inject the error-feedback
+                        # residual (stored UNSCALED — the dynamic loss
+                        # scale can change between micro-batches, so the
+                        # S-scaled slab gets ef*S), then encode codes +
+                        # per-row scale. Gradients arrive pre-reduced in
+                        # the pjit engine, so the encode needs no summation
+                        # headroom (n_summands=1)
+                        slab = arena_mod.pack(g, layout, dtype=jnp.float32)
+                        if use_ef:
+                            slab = slab + st["ef"].data * sc["scale"]
+                    else:
+                        slab = arena_mod.pack(g, layout, dtype=wire)
                 # the flag is computed over the packed slab BEFORE the fold
                 # commits (for fp8: pre-encode, residual included — finite
                 # inputs always encode to finite codes); under shard_map it
@@ -333,7 +344,8 @@ def make_adama_step(cfg: ModelConfig, opt: OptimizerConfig, *, remat=False,
                 ok = fault_mod.apply_skip(fault, ok, micro=i,
                                           step=st["step"])
                 if fp8:
-                    codes, gs = fp8_encode_rows(slab)
+                    with jax.named_scope(GRAD_PACK_SCOPE):
+                        codes, gs = fp8_encode_rows(slab)
                     st, _ = state_store.fold_state(
                         st, codes, beta1=b1, beta2=b2,
                         scale=scaler_mod.scale_into_fold(1.0 / n, sc),
@@ -343,10 +355,11 @@ def make_adama_step(cfg: ModelConfig, opt: OptimizerConfig, *, remat=False,
                         # e = (g*S + ef*S - decode)/S, back in unscaled
                         # units; predicated on the SAME flag as the fold,
                         # so a skipped micro-batch leaves ef bitwise
-                        ef_new = (slab - fp8_decode_rows(codes, gs)) \
-                            / sc["scale"]
-                        st = dict(st, ef=st["ef"].with_data(
-                            jnp.where(ok, ef_new, st["ef"].data)))
+                        with jax.named_scope(GRAD_PACK_SCOPE):
+                            ef_new = (slab - fp8_decode_rows(codes, gs)) \
+                                / sc["scale"]
+                            st = dict(st, ef=st["ef"].with_data(
+                                jnp.where(ok, ef_new, st["ef"].data)))
                 else:
                     st, _ = state_store.fold_state(
                         st, slab, beta1=b1, beta2=b2,

@@ -24,6 +24,7 @@ from typing import Any, Dict, Sequence
 import jax
 import jax.numpy as jnp
 
+from repro.configs.base import APPLY_SCOPE, FOLD_SCOPE, GRAD_PACK_SCOPE
 from repro.core import arena as arena_mod
 from repro.core.arena import Arena
 
@@ -98,6 +99,7 @@ def is_arena_state(state: State) -> bool:
     return is_arena_backed(state["m"])
 
 
+@jax.named_scope(FOLD_SCOPE)
 def begin_minibatch(state: State, beta1: float, beta2: float,
                     m_devices: int = 1) -> State:
     """m <- b1*m ; v <- M*b2*v (Eq. 6's M*beta2 pre-scale; M=1 single device).
@@ -139,28 +141,31 @@ def accumulate(state: State, grads, beta1: float, beta2: float,
     BITWISE no-op fold and the return becomes (new_state, flag)."""
     if is_arena_state(state):
         from repro.core import state_store
-        g = arena_mod.pack(grads, state["m"].layout, dtype=grad_dtype)
+        with jax.named_scope(GRAD_PACK_SCOPE):
+            g = arena_mod.pack(grads, state["m"].layout, dtype=grad_dtype)
         return state_store.fold_state(state, g, beta1=beta1, beta2=beta2,
                                       scale=scale, decay=decay,
                                       grad_dtype=grad_dtype, guard=guard)
     if guard is not None:
         raise ValueError("finite guards require the arena fold path "
                          "(OptimizerConfig arena=True use_pallas=True)")
-    if decay is not None:
-        state = {"m": jax.tree.map(lambda m: decay[0] * m, state["m"]),
-                 "v": jax.tree.map(lambda v: decay[1] * v, state["v"]),
-                 "step": state["step"]}
-    if use_pallas:
-        from repro.kernels.ops import adama_accumulate_tree
-        m, v = adama_accumulate_tree(state["m"], state["v"], grads,
-                                     beta1=beta1, beta2=beta2, scale=scale)
+    with jax.named_scope(FOLD_SCOPE):
+        if decay is not None:
+            state = {"m": jax.tree.map(lambda m: decay[0] * m, state["m"]),
+                     "v": jax.tree.map(lambda v: decay[1] * v, state["v"]),
+                     "step": state["step"]}
+        if use_pallas:
+            from repro.kernels.ops import adama_accumulate_tree
+            m, v = adama_accumulate_tree(state["m"], state["v"], grads,
+                                         beta1=beta1, beta2=beta2,
+                                         scale=scale)
+            return {"m": m, "v": v, "step": state["step"]}
+        m = jax.tree.map(lambda m_, g: m_ + (1 - beta1) *
+                         (g.astype(jnp.float32) * scale), state["m"], grads)
+        v = jax.tree.map(lambda v_, g: v_ + (1 - beta2) *
+                         jnp.square(g.astype(jnp.float32) * scale),
+                         state["v"], grads)
         return {"m": m, "v": v, "step": state["step"]}
-    m = jax.tree.map(lambda m_, g: m_ + (1 - beta1) *
-                     (g.astype(jnp.float32) * scale), state["m"], grads)
-    v = jax.tree.map(lambda v_, g: v_ + (1 - beta2) *
-                     jnp.square(g.astype(jnp.float32) * scale),
-                     state["v"], grads)
-    return {"m": m, "v": v, "step": state["step"]}
 
 
 def accumulate_leaf(m, v, g, beta1: float, beta2: float, use_pallas=False):
@@ -200,6 +205,7 @@ def allreduce_states(state: State, axis_names: Sequence[str],
     return dict(state, m=m, v=v)
 
 
+@jax.named_scope(APPLY_SCOPE)
 def finalize(params, state: State, *, lr, beta1: float, beta2: float,
              eps: float = 1e-8, weight_decay: float = 0.0,
              use_pallas: bool = False, guard=None):

@@ -51,7 +51,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.configs.base import ModelConfig
+from repro.configs.base import (FOLD_SCOPE, GRAD_PACK_SCOPE, MODEL_SCOPE,
+                                RECOMPUTE_SCOPE, ModelConfig)
 from repro.core import arena as arena_mod
 from repro.core.adama import accumulate_leaf, is_arena_state
 from repro.core.arena import STACK_KEYS
@@ -82,6 +83,7 @@ class ZeroStream:
     zero_async: bool = False
 
 
+@jax.named_scope(FOLD_SCOPE)
 def _fold_tree(m, v, g, beta1, beta2, use_pallas):
     fold = functools.partial(accumulate_leaf, beta1=beta1, beta2=beta2,
                              use_pallas=use_pallas)
@@ -123,6 +125,7 @@ def _zero_rank(zero):
     return zero.rank if zero.rank is not None else _lin_index(zero.axis_names)
 
 
+@jax.named_scope(GRAD_PACK_SCOPE)
 def _fp8_wire_slab(slab, axis_names, ef_c, ef_scale, own_offset, own_rows,
                    row0):
     """Shared fp8-wire front half for a packed gradient slab (used by this
@@ -156,6 +159,7 @@ def _fp8_wire_slab(slab, axis_names, ef_c, ef_scale, own_offset, own_rows,
     return codes, s_own, slab
 
 
+@jax.named_scope(GRAD_PACK_SCOPE)
 def _fp8_ef_update(ef_c, ok, slab, codes, s_own, ef_scale, own_offset,
                    own_rows, row0, axis_names):
     """Back half of the fp8 wire: fold the quantization error THIS device
@@ -256,12 +260,14 @@ def layerwise_loss_and_fold(cfg: ModelConfig, params, batch, state, *,
         positions = jnp.broadcast_to(jnp.arange(total, dtype=jnp.int32),
                                      (b, total))
 
+        @jax.named_scope(MODEL_SCOPE)
         def pre(rest_):
             xt = embed_tokens(cfg, rest_, tokens, positions[:, p_:])
             return jnp.concatenate([patches, xt], axis=1)
     else:
         positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
 
+        @jax.named_scope(MODEL_SCOPE)
         def pre(rest_):
             return embed_tokens(cfg, rest_, tokens, positions)
 
@@ -275,6 +281,7 @@ def layerwise_loss_and_fold(cfg: ModelConfig, params, batch, state, *,
 
     from repro.sharding.ctx import maybe_shard
 
+    @jax.named_scope(MODEL_SCOPE)
     def fwd_stack(stack, x, knd):
         def f(carry, lp):
             h, auxs = carry
@@ -294,6 +301,7 @@ def layerwise_loss_and_fold(cfg: ModelConfig, params, batch, state, *,
         x, auxs, saved_inputs[name] = fwd_stack(params[name], x, knd)
         aux_total = aux_total + auxs
 
+    @jax.named_scope(MODEL_SCOPE)
     def post(rest_, xn):
         xf = xn[:, -s:] if cfg.arch_type == "vlm" else xn
         h = md.apply_norm(cfg, rest_, xf, "final_norm_")
@@ -340,8 +348,9 @@ def layerwise_loss_and_fold(cfg: ModelConfig, params, batch, state, *,
             # flag (skip => replicated columns stay bitwise).
             rdm, rdv = (decay if zero is None or zero.replicated_decay is None
                         else zero.replicated_decay)
-            m_acc = state_store._guarded_begin_micro(mc, m_acc, rdm, ok)
-            v_acc = state_store._guarded_begin_micro(vc, v_acc, rdv, ok)
+            with jax.named_scope(FOLD_SCOPE):
+                m_acc = state_store._guarded_begin_micro(mc, m_acc, rdm, ok)
+                v_acc = state_store._guarded_begin_micro(vc, v_acc, rdv, ok)
     else:
         codec = None
         new_m = dict(state["m"])
@@ -359,9 +368,11 @@ def layerwise_loss_and_fold(cfg: ModelConfig, params, batch, state, *,
             else:
                 (dx_c, m_c, v_c), ok_c = carry, None
             j, lp, xin = xs
-            _, vjp = jax.vjp(
+            # the layer's forward is recomputed here (only its input was
+            # saved): a backward cost, as remat's recompute is
+            _, vjp = jax.vjp(jax.named_scope(RECOMPUTE_SCOPE)(
                 lambda lp_, xi_: apply_block(cfg, lp_, xi_, positions,
-                                             kind=knd, causal=causal),
+                                             kind=knd, causal=causal)),
                 lp, xin)
             dlp, dxin = vjp((dx_c, scale))               # aux cotangent=scale
             out = _fold_layer(m_c, v_c, dlp, j, spec, lay if arena_st
@@ -442,7 +453,8 @@ def _fold_layer(m_c, v_c, dlp, j, spec, lay, beta1, beta2, use_pallas, decay,
         assert guard_ok is not None, \
             "fp8 wire requires finite guards (e4m3 has no inf; NaN codes " \
             "are the only overflow signal)"
-        g2 = arena_mod.pack_layer(dlp, spec, dtype=jnp.float32)
+        with jax.named_scope(GRAD_PACK_SCOPE):
+            g2 = arena_mod.pack_layer(dlp, spec, dtype=jnp.float32)
         if zero is not None:
             base, lslice, block = zero.plan.stack_slice(spec.name)
             off = base + j * lslice
@@ -471,7 +483,8 @@ def _fold_layer(m_c, v_c, dlp, j, spec, lay, beta1, beta2, use_pallas, decay,
         return m2, v2, ef_c, ok
     if lay is not None:
         from repro.core import state_store
-        g2 = arena_mod.pack_layer(dlp, spec, dtype=grad_dtype)
+        with jax.named_scope(GRAD_PACK_SCOPE):
+            g2 = arena_mod.pack_layer(dlp, spec, dtype=grad_dtype)
         if zero is not None:
             g2 = lax.psum_scatter(g2, zero.axis_names, scatter_dimension=0,
                                   tiled=True)
@@ -529,8 +542,9 @@ def _fold_rest(m_acc, v_acc, d_rest, lay, beta1, beta2, decay, codec,
             for b in zero.plan.grad_buckets():
                 if b.kind != "rest":
                     continue
-                slab = arena_mod.pack_rest_rows(d_rest, lay, b.start,
-                                                b.stop, dtype=jnp.float32)
+                with jax.named_scope(GRAD_PACK_SCOPE):
+                    slab = arena_mod.pack_rest_rows(d_rest, lay, b.start,
+                                                    b.stop, dtype=jnp.float32)
                 row0 = _zero_rank(zero) * b.slice_rows
                 codes, s_own, slab = _fp8_wire_slab(
                     slab, zero.axis_names, ef_c, ef_scale, b.own_offset,
@@ -550,7 +564,8 @@ def _fold_rest(m_acc, v_acc, d_rest, lay, beta1, beta2, decay, codec,
                                           b.slice_rows, row0,
                                           zero.axis_names)
         else:
-            g2 = arena_mod.pack_rest(d_rest, lay, dtype=jnp.float32)
+            with jax.named_scope(GRAD_PACK_SCOPE):
+                g2 = arena_mod.pack_rest(d_rest, lay, dtype=jnp.float32)
             off, rows = lay.rest.row, lay.rest.rows
             codes, s_col, g2 = _fp8_wire_slab(g2, None, ef_c, ef_scale,
                                               off, rows, off)
@@ -569,8 +584,9 @@ def _fold_rest(m_acc, v_acc, d_rest, lay, beta1, beta2, decay, codec,
         rbks = [b for b in zero.plan.grad_buckets() if b.kind == "rest"]
 
         def issue(b):
-            slab = arena_mod.pack_rest_rows(d_rest, lay, b.start, b.stop,
-                                            dtype=grad_dtype)
+            with jax.named_scope(GRAD_PACK_SCOPE):
+                slab = arena_mod.pack_rest_rows(d_rest, lay, b.start, b.stop,
+                                                dtype=grad_dtype)
             return lax.psum_scatter(slab, zero.axis_names,
                                     scatter_dimension=0, tiled=True)
 
@@ -613,7 +629,8 @@ def _fold_rest(m_acc, v_acc, d_rest, lay, beta1, beta2, decay, codec,
                 m_acc, v_acc, ok = fold(m_acc, v_acc, ok, b, issue(b))
         return (m_acc, v_acc, ok) if guard_ok is not None \
             else (m_acc, v_acc)
-    g2 = arena_mod.pack_rest(d_rest, lay, dtype=grad_dtype)
+    with jax.named_scope(GRAD_PACK_SCOPE):
+        g2 = arena_mod.pack_rest(d_rest, lay, dtype=grad_dtype)
     if ok is not None:
         ok = jnp.logical_and(ok, jnp.isfinite(g2).all())
         m_acc, v_acc, _ = state_store.fold_slice(
@@ -649,6 +666,7 @@ def _layerwise_audio(cfg, params, batch, state, *, beta1, beta2, scale,
     # encoder forward (save layer inputs)
     e0 = frames + md.sinusoidal_positions(epos, cfg.d_model).astype(frames.dtype)
 
+    @jax.named_scope(MODEL_SCOPE)
     def enc_f(carry, lp):
         h = carry
         y, _ = apply_block(cfg, lp, h, epos, kind="dense", causal=False)
@@ -656,14 +674,17 @@ def _layerwise_audio(cfg, params, batch, state, *, beta1, beta2, scale,
     eN, enc_saved = lax.scan(enc_f, maybe_shard(e0, "dp", None, "model"),
                              params["enc_blocks"])
 
+    @jax.named_scope(MODEL_SCOPE)
     def enc_norm(rest_, en):
         return md.apply_norm(cfg, rest_, en, "enc_norm_")
     enc_out, encn_vjp = jax.vjp(enc_norm, rest, eN)
 
+    @jax.named_scope(MODEL_SCOPE)
     def pre(rest_):
         return embed_tokens(cfg, rest_, tokens, positions)
     x0, pre_vjp = jax.vjp(pre, rest)
 
+    @jax.named_scope(MODEL_SCOPE)
     def dec_block(lp, x, eo):
         enc_kv = md.encode_cross_kv(lp, eo)
         y, a = apply_block(cfg, lp, x, positions, kind="dec", causal=True,
@@ -677,6 +698,7 @@ def _layerwise_audio(cfg, params, batch, state, *, beta1, beta2, scale,
     xN, dec_saved = lax.scan(dec_f, maybe_shard(x0, "dp", None, "model"),
                              params["blocks"])
 
+    @jax.named_scope(MODEL_SCOPE)
     def post(rest_, xn):
         h = md.apply_norm(cfg, rest_, xn, "final_norm_")
         logits = (h @ rest_["lm_head"].astype(h.dtype)).astype(jnp.float32)
@@ -702,8 +724,9 @@ def _layerwise_audio(cfg, params, batch, state, *, beta1, beta2, scale,
         if decay is not None:            # replicated columns: once per micro
             rdm, rdv = (decay if zero is None or zero.replicated_decay is None
                         else zero.replicated_decay)
-            m0 = state_store._guarded_begin_micro(mc, m0, rdm, ok)
-            v0 = state_store._guarded_begin_micro(vc, v0, rdv, ok)
+            with jax.named_scope(FOLD_SCOPE):
+                m0 = state_store._guarded_begin_micro(mc, m0, rdm, ok)
+                v0 = state_store._guarded_begin_micro(vc, v0, rdv, ok)
         dec_spec, enc_spec = lay.stack("blocks"), lay.stack("enc_blocks")
     else:
         codec = None
@@ -766,9 +789,9 @@ def _layerwise_audio(cfg, params, batch, state, *, beta1, beta2, scale,
         else:
             (dx_c, m_c, v_c), ok_c = carry, None
         j, lp, xin = xs
-        _, vjp = jax.vjp(
+        _, vjp = jax.vjp(jax.named_scope(RECOMPUTE_SCOPE)(
             lambda lp_, xi_: apply_block(cfg, lp_, xi_, epos, kind="dense",
-                                         causal=False), lp, xin)
+                                         causal=False)), lp, xin)
         dlp, dxin = vjp((dx_c, scale))
         out = _fold_layer(m_c, v_c, dlp, j, enc_spec, lay, beta1, beta2,
                           use_pallas, decay, codec, zero, grad_dtype,

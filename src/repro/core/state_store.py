@@ -59,6 +59,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.configs.base import FOLD_SCOPE
 from repro.core import arena as arena_mod
 from repro.core.arena import Arena, ArenaLayout
 from repro.kernels.adama_accum import LANES
@@ -397,18 +398,19 @@ def fold(m_codec, v_codec, m_parts, v_parts, g, *, beta1, beta2, scale=1.0,
     replicated decay — a bitwise no-op when the flag is false, and the
     return becomes (m_parts, v_parts, flag)."""
     mc, vc = get_codec(m_codec, "m"), get_codec(v_codec, "v")
-    flag = _resolve_guard(guard, g)
-    if decay is not None or replicated_decay is not None:
-        rdm, rdv = _decay_pair(decay if replicated_decay is None
-                               else replicated_decay)
-        m_parts = _guarded_begin_micro(mc, m_parts, rdm, flag)
-        v_parts = _guarded_begin_micro(vc, v_parts, rdv, flag)
     from repro.kernels import fused_step
-    return fused_step.arena_fold(tuple(m_parts), tuple(v_parts), g,
-                                 beta1=beta1, beta2=beta2, scale=scale,
-                                 decay=decay, m_codec=mc.kernel,
-                                 v_codec=vc.kernel, grad_dtype=grad_dtype,
-                                 grad_scale=grad_scale, guard=flag)
+    with jax.named_scope(FOLD_SCOPE):
+        flag = _resolve_guard(guard, g)
+        if decay is not None or replicated_decay is not None:
+            rdm, rdv = _decay_pair(decay if replicated_decay is None
+                                   else replicated_decay)
+            m_parts = _guarded_begin_micro(mc, m_parts, rdm, flag)
+            v_parts = _guarded_begin_micro(vc, v_parts, rdv, flag)
+        return fused_step.arena_fold(tuple(m_parts), tuple(v_parts), g,
+                                     beta1=beta1, beta2=beta2, scale=scale,
+                                     decay=decay, m_codec=mc.kernel,
+                                     v_codec=vc.kernel, grad_dtype=grad_dtype,
+                                     grad_scale=grad_scale, guard=flag)
 
 
 def fold_slice(m_codec, v_codec, m_parts, v_parts, g, row_offset, *,
@@ -423,13 +425,12 @@ def fold_slice(m_codec, v_codec, m_parts, v_parts, g, row_offset, *,
     own begin_micro decay with the same flag."""
     mc, vc = get_codec(m_codec, "m"), get_codec(v_codec, "v")
     from repro.kernels import fused_step
-    return fused_step.arena_fold_slice(tuple(m_parts), tuple(v_parts), g,
-                                       row_offset, beta1=beta1, beta2=beta2,
-                                       block=block, scale=scale, decay=decay,
-                                       m_codec=mc.kernel, v_codec=vc.kernel,
-                                       grad_dtype=grad_dtype,
-                                       grad_scale=grad_scale,
-                                       guard=_resolve_guard(guard, g))
+    with jax.named_scope(FOLD_SCOPE):
+        return fused_step.arena_fold_slice(
+            tuple(m_parts), tuple(v_parts), g, row_offset, beta1=beta1,
+            beta2=beta2, block=block, scale=scale, decay=decay,
+            m_codec=mc.kernel, v_codec=vc.kernel, grad_dtype=grad_dtype,
+            grad_scale=grad_scale, guard=_resolve_guard(guard, g))
 
 
 def apply(m_codec, v_codec, p, m_parts, v_parts, *, lr, bc1, bc2, eps=1e-8,

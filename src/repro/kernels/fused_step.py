@@ -509,6 +509,7 @@ def arena_fold(m, v, g, *, beta1: float, beta2: float, scale=1.0,
             out_shape=_out_shapes(xs[:nm + nv]),
             input_output_aliases={1 + k: k for k in range(nm + nv)},
             interpret=_interpret() if interpret is None else interpret,
+            name="arena_fold",
         )(sc, *xs)
 
     row_cols = [c.row_indexed for c in mk.cols + vk.cols]
@@ -580,6 +581,7 @@ def arena_fold_slice(m, v, g, row_offset, *, beta1: float, beta2: float,
         out_shape=_out_shapes(m_parts + v_parts),
         input_output_aliases={2 + k: k for k in range(nm + nv)},
         interpret=_interpret() if interpret is None else interpret,
+        name="arena_fold_slice",
     )
     # the offset addresses global arena rows, so under a pjit mesh every
     # device folds the whole slice into the whole arena (the shard_map
@@ -678,6 +680,7 @@ def arena_apply(p, m, v, *, lr, bc1, bc2, eps: float = 1e-8,
             out_shape=out_shape,
             input_output_aliases={1: 0},
             interpret=_interpret() if interpret is None else interpret,
+            name="arena_apply",
         )(sc, p, *mv)
 
     out = _on_mesh(call, (scalars, p, *m_parts, *v_parts),

@@ -23,7 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from repro.configs.base import ModelConfig
+from repro.configs.base import MODEL_SCOPE, ModelConfig
 from repro.models import modules as md
 
 Params = Dict[str, Any]
@@ -425,5 +425,6 @@ def cross_entropy(logits, labels):
 
 
 def loss_fn(cfg: ModelConfig, params: Params, batch, *, remat: bool = False):
-    logits, aux = forward(cfg, params, batch, remat=remat)
-    return cross_entropy(logits, batch["labels"]) + aux
+    with jax.named_scope(MODEL_SCOPE):
+        logits, aux = forward(cfg, params, batch, remat=remat)
+        return cross_entropy(logits, batch["labels"]) + aux

@@ -7,7 +7,14 @@ FaultSpec into the compiled step (nan/inf/zero/skip) or arms a host-side
 save — the worst-case kill the auto-resume path must survive. With
 `finite_guard=True` the loop surfaces loss_scale / skipped_micro_batches /
 consec_skips in the logs and aborts when `scaler_abort_after` consecutive
-micro-batches skip (a run that is only skipping is not training)."""
+micro-batches skip (a run that is only skipping is not training).
+
+Host spans (`jax.profiler` annotations, recorded only while a profiler
+trace runs, on the device trace's clock): `train.init` (optimizer init,
+restore, placement), `train.compile`, and per step `train.step` (step
+number `i + 1`) holding `train.batch` (build and place the batch),
+`train.dispatch` (the jitted step call), `train.sync` (the host reads the
+loss), `train.log` and `train.checkpoint`."""
 from __future__ import annotations
 
 import contextlib
@@ -16,6 +23,7 @@ import time
 from typing import Any, Dict
 
 import jax
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import RunConfig
@@ -81,74 +89,87 @@ def train(run: RunConfig, *, lr_schedule=None, log_fn=print,
                                         lr_schedule=lr_schedule,
                                         state_shards=state_shards,
                                         fault=fault)
-    opt_state = opt_init(params)
-    start = 0
-    if run.checkpoint_dir:
-        last = ckpt.latest_step(run.checkpoint_dir)
-        if last is not None:
-            tree = {"params": params, "opt": opt_state}
-            tree = ckpt.restore(run.checkpoint_dir, last,
-                                jax.eval_shape(lambda: tree))
-            params, opt_state = tree["params"], tree["opt"]
-            start = last
-            log_fn(f"[train] restored step {last}")
-
     if data is None:
         data = make_data(cfg, run.shape, seed=run.seed)
     every = run.checkpoint_every or max(run.log_every * 5, 50)
     losses = []
     compiled, compile_s = None, 0.0
+    start = 0
     with mesh_ctx:                  # row-range sharding ctx (no-op if empty)
-        batch_sharding, jit_kw = None, {}
-        if mesh is not None:
-            p_sh, o_sh, batch_sharding, rep = zero1_shardings(
-                mesh, opt, params, opt_state)
-            params, opt_state = jax.device_put((params, opt_state),
-                                               (p_sh, o_sh))
-            jit_kw = dict(in_shardings=(p_sh, o_sh, batch_sharding),
-                          out_shardings=(p_sh, o_sh, rep))
+        with TraceAnnotation("train.init"):
+            opt_state = opt_init(params)
+            if run.checkpoint_dir:
+                last = ckpt.latest_step(run.checkpoint_dir)
+                if last is not None:
+                    tree = {"params": params, "opt": opt_state}
+                    tree = ckpt.restore(run.checkpoint_dir, last,
+                                        jax.eval_shape(lambda: tree))
+                    params, opt_state = tree["params"], tree["opt"]
+                    start = last
+                    log_fn(f"[train] restored step {last}")
+            batch_sharding, jit_kw = None, {}
+            if mesh is not None:
+                p_sh, o_sh, batch_sharding, rep = zero1_shardings(
+                    mesh, opt, params, opt_state)
+                params, opt_state = jax.device_put((params, opt_state),
+                                                   (p_sh, o_sh))
+                jit_kw = dict(in_shardings=(p_sh, o_sh, batch_sharding),
+                              out_shardings=(p_sh, o_sh, rep))
         jstep = jax.jit(step_fn, donate_argnums=(0, 1), **jit_kw)
         if start < run.steps:
             # compile ahead of step 1 so no step time includes it; the jit
             # call below reuses this executable
             t0 = time.perf_counter()
-            compiled = jstep.lower(
-                params, opt_state,
-                jax.device_put(data.batch(start), batch_sharding)).compile()
+            with TraceAnnotation("train.compile"):
+                compiled = jstep.lower(
+                    params, opt_state,
+                    jax.device_put(data.batch(start), batch_sharding)
+                ).compile()
             compile_s = time.perf_counter() - t0
             log_fn(f"[train] step compiled in {compile_s:.2f}s")
         t0 = time.time()
         for i in range(start, run.steps):
-            batch = jax.device_put(data.batch(i), batch_sharding)
-            params, opt_state, metrics = jstep(params, opt_state, batch)
-            losses.append(float(metrics["loss"]))
-            consec = int(metrics.get("consec_skips", 0))
-            if opt.scaler_abort_after and consec >= opt.scaler_abort_after:
-                raise RuntimeError(
-                    f"aborting at step {i + 1}: {consec} consecutive "
-                    f"micro-batches skipped non-finite (>= scaler_abort_"
-                    f"after={opt.scaler_abort_after}); loss_scale="
-                    f"{float(metrics.get('loss_scale', 1.0)):g} — the run "
-                    f"is diverging, not merely overflowing")
-            if (i + 1) % run.log_every == 0:
-                dt = (time.time() - t0) / (i + 1 - start)
-                extra = ""
-                if "loss_scale" in metrics:
-                    extra = (f" scale={float(metrics['loss_scale']):g}"
-                             f" skipped="
-                             f"{int(metrics['skipped_micro_batches'])}")
-                log_fn(f"[train] step {i+1}/{run.steps} "
-                       f"loss={losses[-1]:.4f}{extra} ({dt:.2f}s/step)")
-            if faults_mod.crash_due(fault, i):
-                # update committed, checkpoint NOT saved: the auto-resume
-                # path above must replay from the last saved step bitwise
-                raise faults_mod.InjectedCrash(
-                    f"injected crash after step {i + 1}'s update, before "
-                    f"its save")
-            if run.checkpoint_dir and (i + 1) % every == 0:
-                ckpt.save(run.checkpoint_dir, i + 1,
-                          {"params": params, "opt": opt_state},
-                          keep=run.keep_last_n)
+            with StepTraceAnnotation("train.step", step_num=i + 1):
+                with TraceAnnotation("train.batch"):
+                    batch = jax.device_put(data.batch(i), batch_sharding)
+                with TraceAnnotation("train.dispatch"):
+                    params, opt_state, metrics = jstep(params, opt_state,
+                                                       batch)
+                with TraceAnnotation("train.sync"):
+                    losses.append(float(metrics["loss"]))
+                    consec = int(metrics.get("consec_skips", 0))
+                if opt.scaler_abort_after and \
+                        consec >= opt.scaler_abort_after:
+                    raise RuntimeError(
+                        f"aborting at step {i + 1}: {consec} consecutive "
+                        f"micro-batches skipped non-finite (>= scaler_"
+                        f"abort_after={opt.scaler_abort_after}); loss_"
+                        f"scale={float(metrics.get('loss_scale', 1.0)):g}"
+                        f" — the run is diverging, not merely overflowing")
+                if (i + 1) % run.log_every == 0:
+                    with TraceAnnotation("train.log"):
+                        dt = (time.time() - t0) / (i + 1 - start)
+                        extra = ""
+                        if "loss_scale" in metrics:
+                            skipped = int(metrics["skipped_micro_batches"])
+                            extra = (f" scale="
+                                     f"{float(metrics['loss_scale']):g}"
+                                     f" skipped={skipped}")
+                        log_fn(f"[train] step {i+1}/{run.steps} "
+                               f"loss={losses[-1]:.4f}{extra} "
+                               f"({dt:.2f}s/step)")
+                if faults_mod.crash_due(fault, i):
+                    # update committed, checkpoint NOT saved: the
+                    # auto-resume path above must replay from the last
+                    # saved step bitwise
+                    raise faults_mod.InjectedCrash(
+                        f"injected crash after step {i + 1}'s update, "
+                        f"before its save")
+                if run.checkpoint_dir and (i + 1) % every == 0:
+                    with TraceAnnotation("train.checkpoint"):
+                        ckpt.save(run.checkpoint_dir, i + 1,
+                                  {"params": params, "opt": opt_state},
+                                  keep=run.keep_last_n)
     if run.checkpoint_dir:
         ckpt.save(run.checkpoint_dir, run.steps,
                   {"params": params, "opt": opt_state},
