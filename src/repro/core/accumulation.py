@@ -378,7 +378,12 @@ def make_adama_step(cfg: ModelConfig, opt: OptimizerConfig, *, remat=False,
             state = dict(state, step=state["step"] + applied.astype(jnp.int32))
         elif use_arena:
             # decay is fused into fold 0 (no standalone state-sized pass);
-            # 1/N rides in-kernel as the fold's static scale
+            # 1/N rides in-kernel as the fold's static scale. The fold is
+            # the slab's only reader here, so unless the kernel must split
+            # rows over a mesh (or states are psum'd under shard_map) it
+            # gathers the gradient from the leaves and no slab is written
+            from repro.sharding.ctx import kernel_mesh
+            leaves = not axis_names and kernel_mesh() is None and not fp8
             state = dict(opt_state, step=opt_state["step"] + 1)
 
             def body(carry, xs):
@@ -387,7 +392,7 @@ def make_adama_step(cfg: ModelConfig, opt: OptimizerConfig, *, remat=False,
                 l, g = jax.value_and_grad(lambda p: loss(p, mb))(params)
                 st = adama.accumulate(st, g, b1, b2, scale=1.0 / n,
                                       decay=_fold_decay(i, b1, b2, m_devices),
-                                      grad_dtype=wire)
+                                      grad_dtype=wire, from_leaves=leaves)
                 return (st, lsum + l), None
 
             (state, lsum), _ = lax.scan(body, (state, 0.0),

@@ -125,7 +125,8 @@ def begin_minibatch(state: State, beta1: float, beta2: float,
 
 def accumulate(state: State, grads, beta1: float, beta2: float,
                use_pallas: bool = False, scale: float = 1.0,
-               decay=None, grad_dtype=jnp.float32, guard=None) -> State:
+               decay=None, grad_dtype=jnp.float32, guard=None,
+               from_leaves: bool = False) -> State:
     """Fold one micro-batch's gradients into (m, v); Algorithm 2 inner loop.
 
     `scale` multiplies g before the fold (Alg. 1 line 6's 1/N, applied
@@ -138,11 +139,20 @@ def accumulate(state: State, grads, beta1: float, beta2: float,
     `guard` (arena path only; OptimizerConfig.finite_guard): True
     self-checks the packed slab, a traced bool (psum-agreed under
     shard_map) is used verbatim — either way a non-finite micro-batch is a
-    BITWISE no-op fold and the return becomes (new_state, flag)."""
+    BITWISE no-op fold and the return becomes (new_state, flag).
+
+    `from_leaves` (arena path, unguarded) hands the fold the gradient
+    tree's sources instead of a packed slab (arena.gather_sources): the
+    fold kernel gathers each row block from the leaves and the slab is
+    never written. Only the relayout copies of leaves that are not a
+    bitcast of (rows, LANES) remain under the pack scope."""
     if is_arena_state(state):
         from repro.core import state_store
+        layout = state["m"].layout
         with jax.named_scope(GRAD_PACK_SCOPE):
-            g = arena_mod.pack(grads, state["m"].layout, dtype=grad_dtype)
+            g = (arena_mod.gather_sources(grads, layout, grad_dtype)
+                 if from_leaves else
+                 arena_mod.pack(grads, layout, dtype=grad_dtype))
         return state_store.fold_state(state, g, beta1=beta1, beta2=beta2,
                                       scale=scale, decay=decay,
                                       grad_dtype=grad_dtype, guard=guard)

@@ -33,6 +33,8 @@ in-pass so the moments still accumulate exactly.
 """
 from __future__ import annotations
 
+import dataclasses
+import functools
 import math
 import warnings
 from dataclasses import dataclass
@@ -260,18 +262,19 @@ def _check_pack_dtype(dtype):
 
 
 def _pack_region(leaves, specs, region_rows, lead: Tuple[int, ...] = (),
-                 dtype=jnp.float32):
+                 dtype=jnp.float32, front: int = 0):
     """Concatenate leaves (each reshaped (*lead, -1), zero-padded to whole
-    rows) into a (*lead, region_rows, LANES) `dtype` block."""
+    rows) into a (*lead, region_rows, LANES) `dtype` block, after `front`
+    zero rows."""
     _check_pack_dtype(dtype)
-    mats = []
+    mats = [jnp.zeros(lead + (front, LANES), dtype)] if front else []
     for x, spec in zip(leaves, specs):
         flat = x.reshape(lead + (-1,)).astype(dtype)
         pad = spec.rows * LANES - spec.size
         if pad:
             flat = jnp.pad(flat, [(0, 0)] * len(lead) + [(0, pad)])
         mats.append(flat.reshape(lead + (spec.rows, LANES)))
-    used = sum(s.rows for s in specs)
+    used = front + sum(s.rows for s in specs)
     if region_rows > used:
         mats.append(jnp.zeros(lead + (region_rows - used, LANES), dtype))
     return jnp.concatenate(mats, axis=len(lead)) if len(mats) > 1 else mats[0]
@@ -380,6 +383,219 @@ def unpack(arena: jnp.ndarray, layout: ArenaLayout, dtype=None):
         return rest_tree
     out.update(rest_tree)
     return out
+
+
+# ---------------------------------------------------------------------------
+# Gradient sources: the whole-arena fold reads the gradient tree in place
+# ---------------------------------------------------------------------------
+#
+# Instead of packing a micro-batch's gradient into a (rows, LANES) slab, the
+# fold kernel (kernels/fused_step.py::arena_fold) can gather each row block
+# of gradient straight from a handful of SOURCE arrays, one DMA per source
+# run the block touches. A source is either
+#
+#   direct  one leaf whose (rows, LANES) view is free: its last dim is
+#           LANES and its rows come in whole (sublane-tile, LANES) tiles,
+#           so the reshape is a bitcast and the kernel reads the leaf as the
+#           backward left it (cast to the wire in-kernel), or
+#   copy    one leaf that needs a relayout (last dim != LANES, a tail lane
+#           pad, another dtype): one XLA copy into a (rows, LANES) buffer
+#           of the wire dtype; or a run of consecutive sub-tile leaves
+#           (norm scales and biases) packed together, placed so its rows
+#           sit at the same offset within a tile as their arena rows.
+#
+# Stacked sources keep the layer as their leading dim, so every layer of a
+# stack uses the same source at the same inner offset. A DMA moves whole
+# sublane tiles, so the kernel lands each source's tiles in a VMEM staging
+# buffer keyed by (dtype, residue): the residue is the source's arena-row
+# offset within a tile, which the kernel undoes with one static sublane
+# shift. Sources whose tiles can meet in one staging tile take different
+# staging buffers ("lanes" of the same residue).
+
+
+def sublane_tile(dtype) -> int:
+    """Rows of one HBM tile of `dtype` on the TPU (8 for 32-bit, 16 for
+    16-bit): a DMA's row offset and row count are multiples of it."""
+    return 32 // jnp.dtype(dtype).itemsize
+
+
+def _is_direct(spec: LeafSpec) -> bool:
+    shape, dtype = spec.shape, jnp.dtype(spec.dtype)
+    if dtype not in (jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16)):
+        return False
+    t = sublane_tile(dtype)
+    return (len(shape) >= 2 and shape[-1] == LANES and shape[-2] % t == 0
+            and spec.rows % t == 0)
+
+
+@dataclass(frozen=True)
+class GradSource:
+    """One source array of the gathered fold and where its rows go."""
+    stack: int                   # index into layout.stacks; -1: rest region
+    leaves: Tuple[int, ...]      # leaf indices (region flatten order)
+    row: int                     # first arena row, inside the layer/region
+    rows: int                    # arena rows it covers
+    phys_row: int                # its first row inside the source array
+    phys_rows: int               # rows of the source array (per layer)
+    dtype: Any                   # np.dtype of the source array
+    direct: bool                 # the leaf itself, reshaped (no copy)
+    buf: int                     # the kernel's staging buffer
+
+
+@dataclass(frozen=True)
+class GradRun:
+    """One layer's instance of a source: arena rows [start, start+rows)."""
+    start: int
+    rows: int
+    src: int
+    layer: int
+
+
+@dataclass(frozen=True)
+class GradPlan:
+    """Static plan of the gathered fold: sources, staging buffers (dtype,
+    residue within a tile) and, per layer instance, the run of arena rows
+    each source fills. Rows no run covers fold a zero gradient, as the
+    slab's padding does."""
+    layout: ArenaLayout
+    wire: Any                    # np.dtype of the gradient wire
+    sources: Tuple[GradSource, ...]
+    bufs: Tuple[Tuple[Any, int], ...]
+    runs: Tuple[GradRun, ...]    # sorted by start, disjoint
+
+
+def _region(layout: ArenaLayout, stack: int):
+    """(specs, base row, layer stride, layers) of region `stack`."""
+    if stack < 0:
+        r = layout.rest
+        return r.leaves, r.row, 0, 1
+    s = layout.stacks[stack]
+    return s.leaves, s.row, s.layer_rows, s.n_layers
+
+
+def _region_sources(specs, stack: int, wire) -> list:
+    """Split one region's leaves into sources (buf left unassigned)."""
+    tw = sublane_tile(wire)
+    out, group = [], []
+
+    def copy(idx):
+        # one leaf's copy is its relayout alone (no front pad, which XLA
+        # would write as a second pass); a group of sub-tile leaves is
+        # placed at its arena offset within a tile
+        row = specs[idx[0]].row
+        rows = sum(specs[k].rows for k in idx)
+        front = row % tw if len(idx) > 1 else 0
+        out.append(GradSource(stack, tuple(idx), row, rows, front,
+                              _align(front + rows, tw), np.dtype(wire),
+                              False, -1))
+
+    for k, spec in enumerate(specs):
+        if spec.rows < tw and not _is_direct(spec):
+            group.append(k)                  # sub-tile leaves share a copy
+            continue
+        if group:
+            copy(group)
+            group = []
+        if _is_direct(spec):
+            out.append(GradSource(stack, (k,), spec.row, spec.rows, 0,
+                                  spec.rows, np.dtype(spec.dtype), True, -1))
+        else:
+            copy([k])
+    if group:
+        copy(group)
+    return out
+
+
+def _tiles(start: int, rows: int, src: GradSource):
+    """[first, last] tile of a run in its staging grid."""
+    t = sublane_tile(src.dtype)
+    shift = start - src.phys_row          # arena row of source row 0
+    lo = src.phys_row // t
+    hi = (src.phys_row + rows - 1) // t
+    return lo + (shift - shift % t) // t, hi + (shift - shift % t) // t
+
+
+@functools.lru_cache(maxsize=None)
+def grad_plan(layout: ArenaLayout, wire=jnp.float32) -> GradPlan:
+    """The gathered fold's static plan for `layout` with the gradient
+    arriving on the `wire` dtype (fp32 or bf16)."""
+    wire = np.dtype(jnp.dtype(wire))
+    assert wire in (np.dtype(np.float32), np.dtype(jnp.bfloat16)), wire
+    sources = []
+    for k in range(-1, len(layout.stacks)):
+        specs = _region(layout, k)[0]
+        sources += _region_sources(specs, k, wire) if specs else []
+    runs = []
+    for sid, src in enumerate(sources):
+        _, base, stride, layers = _region(layout, src.stack)
+        runs += [GradRun(base + j * stride + src.row, src.rows, sid, j)
+                 for j in range(layers)]
+    runs.sort(key=lambda r: r.start)
+
+    def key(src):
+        t = sublane_tile(src.dtype)
+        return src.dtype, (src.row - src.phys_row) % t
+
+    # sources of one key whose runs meet in a staging tile need separate
+    # buffers: colour the conflict graph greedily
+    clash = {sid: set() for sid in range(len(sources))}
+    by_key: Dict[Any, list] = {}
+    for r in runs:
+        by_key.setdefault(key(sources[r.src]), []).append(r)
+    for rs in by_key.values():
+        tiles = [_tiles(r.start, r.rows, sources[r.src]) for r in rs]
+        for a in range(len(rs)):
+            for b in range(a + 1, len(rs)):
+                if tiles[b][0] > tiles[a][1]:
+                    break
+                assert rs[a].src != rs[b].src, "a source meets itself"
+                clash[rs[a].src].add(rs[b].src)
+                clash[rs[b].src].add(rs[a].src)
+    bufs, lanes = [], {}
+    for sid, src in enumerate(sources):
+        taken = {lanes[o] for o in clash[sid] if o in lanes}
+        lane = next(n for n in range(len(sources) + 1)
+                    if (key(src), n) not in taken)
+        lanes[sid] = (key(src), lane)
+        if lanes[sid] not in bufs:
+            bufs.append(lanes[sid])
+        sources[sid] = dataclasses.replace(src, buf=bufs.index(lanes[sid]))
+    return GradPlan(layout, wire, tuple(sources),
+                    tuple(k for k, _ in bufs), tuple(runs))
+
+
+class GradLeaves:
+    """A micro-batch's gradient as the gathered fold reads it: the source
+    arrays of `plan`, built from the gradient tree by `gather_sources`."""
+
+    def __init__(self, arrays: Tuple[jnp.ndarray, ...], plan: GradPlan):
+        self.arrays = tuple(arrays)
+        self.plan = plan
+
+
+def gather_sources(grads, layout: ArenaLayout, wire=jnp.float32) -> GradLeaves:
+    """Gradient tree -> the gathered fold's sources: direct leaves are
+    reshaped to (layers, rows, LANES) views (a bitcast on the chip), the
+    rest copied once into wire-dtype buffers. No (rows, LANES) slab."""
+    plan = grad_plan(layout, wire)
+    stack_items, rest_tree = split_tree(grads)
+    leaves = {-1: layout.rest.treedef.flatten_up_to(rest_tree)}
+    for k, ((name, sub), spec) in enumerate(zip(stack_items, layout.stacks)):
+        assert name == spec.name
+        leaves[k] = spec.treedef.flatten_up_to(sub)
+    arrays = []
+    for src in plan.sources:
+        specs = _region(layout, src.stack)[0]
+        xs = [leaves[src.stack][k] for k in src.leaves]
+        if src.stack < 0:
+            xs = [x[None] for x in xs]
+        if src.direct:
+            arrays.append(xs[0].reshape(xs[0].shape[0], src.rows, LANES))
+        else:
+            arrays.append(_pack_region(xs, [specs[k] for k in src.leaves],
+                                       src.phys_rows, lead=xs[0].shape[:1],
+                                       dtype=plan.wire, front=src.phys_row))
+    return GradLeaves(arrays, plan)
 
 
 # ---------------------------------------------------------------------------

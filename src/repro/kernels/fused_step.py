@@ -27,7 +27,11 @@ new pallas_call scaffolding:
                     (dm, dv) is an SMEM scalar input: passing
                     (beta1, M*beta2) on the FIRST fold of a mini-batch fuses
                     `begin_minibatch` into it, eliminating an entire arena
-                    read+write pass.
+                    read+write pass. The gradient is a packed slab, or the
+                    gradient tree's sources (core/arena.py::GradLeaves):
+                    then each row block's tiles are DMA'd from the leaves
+                    into VMEM staging, one block ahead, and no slab is
+                    ever written to HBM.
   arena_fold_slice  Same fold restricted to rows [offset, offset+rows_g).
                     `offset` is a TRACED scalar-prefetch argument feeding
                     the BlockSpec index maps, so the layer-wise engine
@@ -89,12 +93,14 @@ construction (the master never passes through bf16).
 """
 from __future__ import annotations
 
+import collections
 import functools
 from dataclasses import dataclass, field
 from typing import Any, Callable, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
@@ -329,14 +335,15 @@ def _col_specs(cols, block, *, sliced: bool):
                                           lambda i, off, sc: (off[0] + i, 0)))
             else:
                 specs.append(pl.BlockSpec((block, c.width),
-                                          lambda i: (i, 0)))
+                                          lambda i, *_: (i, 0)))
         else:
             # a single replicated block, revisited by every grid step
             if sliced:
                 specs.append(pl.BlockSpec((1, c.width),
                                           lambda i, off, sc: (0, 0)))
             else:
-                specs.append(pl.BlockSpec((1, c.width), lambda i: (0, 0)))
+                specs.append(pl.BlockSpec((1, c.width),
+                                          lambda i, *_: (0, 0)))
     return specs
 
 
@@ -407,7 +414,6 @@ def _on_mesh(call, args, row_in, row_out, acc=()):
 
 def _fold_body(sc_ref, *refs, mk, vk, beta1, beta2, scale, nm, nv,
                guarded=False, has_gs=False):
-    i = pl.program_id(0)
     m_in, v_in = refs[:nm], refs[nm:nm + nv]
     # in-kernel upcast: a bf16 wire slab becomes fp32 HERE, on the VMEM
     # block — the moments always accumulate in fp32 and no fp32 gradient
@@ -422,8 +428,13 @@ def _fold_body(sc_ref, *refs, mk, vk, beta1, beta2, scale, nm, nv,
         # column — the decoded fp32 gradient exists only on this VMEM block
         g = g * refs[k][...]
         k += 1
-    m_out = refs[k:k + nm]
-    v_out = refs[k + nm:]
+    _fold_update(pl.program_id(0), sc_ref, g, m_in, v_in, refs[k:k + nm],
+                 refs[k + nm:], mk=mk, vk=vk, beta1=beta1, beta2=beta2,
+                 guarded=guarded)
+
+
+def _fold_update(i, sc_ref, g, m_in, v_in, m_out, v_out, *, mk, vk, beta1,
+                 beta2, guarded):
     if guarded:
         ok = sc_ref[2] != 0.0
         m_out = tuple(_GuardedRef(o, p, ok) for o, p in zip(m_out, m_in))
@@ -475,10 +486,26 @@ def arena_fold(m, v, g, *, beta1: float, beta2: float, scale=1.0,
     psum-agreed flag under shard_map) is used verbatim. Either way every
     m/v write is predicated on the flag, so a non-finite micro-batch is a
     BITWISE no-op fold, and the return becomes (m, v, flag). A traced
-    (non-static) `scale` rides in SMEM alongside the flag."""
+    (non-static) `scale` rides in SMEM alongside the flag.
+
+    `g` may instead be a micro-batch's gradient sources
+    (core/arena.py::gather_sources): the kernel then gathers each row
+    block from them (`_fold_leaves`), with no guard, fp8 wire or kernel
+    mesh, and m and v come out bitwise as from the packed slab."""
     mk, vk = kernel_codec("m", m_codec), kernel_codec("v", v_codec)
     m_parts, m_bare = _as_parts(m)
     v_parts, v_bare = _as_parts(v)
+    from repro.core.arena import GradLeaves
+    if isinstance(g, GradLeaves):
+        FOLD_SOURCES["leaves"] += 1
+        out = _fold_leaves(m_parts, v_parts, g, mk, vk, beta1=beta1,
+                           beta2=beta2, scale=scale, decay=decay,
+                           grad_dtype=grad_dtype, grad_scale=grad_scale,
+                           guard=guard, interpret=interpret)
+        m_new, v_new = _split(out, len(m_parts))
+        return (m_new[0] if m_bare else m_new,
+                v_new[0] if v_bare else v_new)
+    FOLD_SOURCES["slab"] += 1
     rows = g.shape[0]
     assert g.shape == (rows, LANES), g.shape
     _check_grad_dtype(g, grad_dtype, grad_scale)
@@ -523,6 +550,211 @@ def arena_fold(m, v, g, *, beta1: float, beta2: float, scale=1.0,
     return (m_new, v_new, flag) if flag is not None else (m_new, v_new)
 
 
+# Folds traced so far, by gradient source: "leaves" (arena_fold gathering
+# from the gradient tree) or "slab" (a packed slab: arena_fold and
+# arena_fold_slice); train/loop.py::train logs the step's counts.
+FOLD_SOURCES: collections.Counter = collections.Counter()
+
+
+def _leaf_tables(plan, block: int):
+    """The gathered fold's scalar-prefetch table (int32, SMEM): per row
+    block the first run it meets and how many, then per run its source,
+    layer, first arena row and row count. Returns (table, offsets of the
+    six columns)."""
+    runs = plan.runs
+    start = np.array([r.start for r in runs], np.int64)
+    rows = np.array([r.rows for r in runs], np.int64)
+    b0 = np.arange(plan.layout.rows // block, dtype=np.int64) * block
+    first = np.searchsorted(start + rows, b0, side="right")
+    count = np.searchsorted(start, b0 + block, side="left") - first
+    # a block no run meets still reads its "first" run's fields: keep the
+    # index inside the table
+    first = np.minimum(first, len(runs) - 1)
+    cols = [first, count, [r.src for r in runs], [r.layer for r in runs],
+            start, rows]
+    offs = np.cumsum([0] + [len(c) for c in cols[:-1]]).tolist()
+    table = np.concatenate([np.asarray(c, np.int64) for c in cols])
+    assert table.max() < 2 ** 31, "arena too large for int32 tables"
+    return table.astype(np.int32), offs
+
+
+def _leaf_fold_body(tbl, sc_ref, *refs, mk, vk, beta1, beta2, scale, nm,
+                    nv, plan, block, offs):
+    """The fold over row block i with the gradient gathered from the
+    plan's sources: while block i folds, block i+1's gradient tiles are in
+    flight into the other staging slot (two slots per staging buffer)."""
+    from repro.core.arena import sublane_tile
+    srcs = plan.sources
+    ns, nbuf = len(srcs), len(plan.bufs)
+    m_in, v_in = refs[:nm], refs[nm:nm + nv]
+    arrays = refs[nm + nv:nm + nv + ns]
+    k = nm + nv + ns
+    m_out, v_out = refs[k:k + nm], refs[k + nm:k + nm + nv]
+    k += nm + nv
+    stag, g_asm, sem = refs[k:k + nbuf], refs[k + nbuf], refs[k + nbuf + 1]
+    c_first, c_count, c_src, c_layer, c_start, c_rows = offs
+    i = pl.program_id(0)
+
+    def each_run(b, fn):
+        """fn(source, layer, first arena row, block) for each run that
+        meets block b: static code per source, picked by the table."""
+        first = tbl[c_first + b]
+
+        def run(r, carry):
+            sid = tbl[c_src + r]
+            for s in range(ns):
+                pl.when(sid == s)(lambda s=s: fn(
+                    s, tbl[c_layer + r], tbl[c_start + r], b))
+            return carry
+        jax.lax.fori_loop(first, first + tbl[c_count + b], run, 0)
+
+    def span(s, start, b):
+        """Arena rows [lo, hi) of block b that source s's run fills."""
+        b0 = b * block
+        return (jnp.maximum(b0, start),
+                jnp.minimum(b0 + block, start + srcs[s].rows))
+
+    def geometry(s):
+        """(tile rows, log2 of it, residue in the tile) of source s."""
+        src = srcs[s]
+        t = sublane_tile(src.dtype)
+        return t, t.bit_length() - 1, (src.row - src.phys_row) % t
+
+    def copies(wait):
+        def fn(s, layer, start, b):
+            # whole source tiles [t0, t0+nt) land in the staging grid at
+            # tile d0; nt is split into power-of-two DMAs so every copy
+            # has a static shape and tile-aligned offsets
+            t, sh, r = geometry(s)
+            lo, hi = span(s, start, b)
+            delta = start - srcs[s].phys_row    # arena row of source row 0
+            t0 = (lo - delta) >> sh
+            nt = ((hi - delta + t - 1) >> sh) - t0
+            d0 = t0 + ((delta - r) >> sh) + (1 if r else 0) \
+                - b * (block // t)
+            slot = b % 2
+            done = jnp.int32(0)
+            most = min(block // t + 1, srcs[s].phys_rows // t)
+            for bit in reversed(range(most.bit_length())):
+                take = (nt >> bit) & 1
+
+                @pl.when(take == 1)
+                def _(done=done, n=1 << bit):
+                    cp = pltpu.make_async_copy(
+                        arrays[s].at[layer, pl.ds(t * (t0 + done), t * n)],
+                        stag[srcs[s].buf].at[slot,
+                                             pl.ds(t * (d0 + done), t * n)],
+                        sem.at[slot])
+                    cp.wait() if wait else cp.start()
+                done = done + take * (1 << bit)
+        return fn
+
+    def prefetch(b, carry):
+        each_run(b, copies(wait=False))
+        return carry
+    # step 0 also starts its own block; the last step has no next block
+    jax.lax.fori_loop(jnp.where(i == 0, 0, i + 1),
+                      jnp.minimum(i + 2, pl.num_programs(0)), prefetch, 0)
+    each_run(i, copies(wait=True))
+
+    def view(b):
+        """Block i's rows of staging buffer b, rounded to the wire."""
+        dtype, r = plan.bufs[b]
+        t = sublane_tile(dtype)
+        x = stag[b][i % 2, pl.ds((t - r) % t, block), :]
+        return x.astype(plan.wire).astype(jnp.float32)
+
+    s = sc_ref[3] if scale is None else scale
+
+    def update(g):
+        _fold_update(i, sc_ref, g * s, m_in, v_in, m_out, v_out, mk=mk,
+                     vk=vk, beta1=beta1, beta2=beta2, guarded=False)
+
+    b0 = i * block
+    first = tbl[c_first + i]
+    sid0, start0 = tbl[c_src + first], tbl[c_start + first]
+    whole = (tbl[c_count + i] == 1) & (start0 <= b0) \
+        & (start0 + tbl[c_rows + first] >= b0 + block)
+    # the common block lies inside one run: fold straight from its
+    # staging buffer
+    buf0 = jnp.int32(0)
+    for k, src in enumerate(srcs):
+        buf0 = jnp.where(sid0 == k, src.buf, buf0)
+    for b in range(nbuf):
+        pl.when(whole & (buf0 == b))(lambda b=b: update(view(b)))
+
+    @pl.when(jnp.logical_not(whole))
+    def _():
+        # a block that meets several runs or padding: rows no run fills
+        # fold a zero gradient, as the slab's padding rows do
+        g_asm[...] = jnp.zeros((block, LANES), jnp.float32)
+        row = jax.lax.broadcasted_iota(jnp.int32, (block, LANES), 0) + b0
+
+        def fill(k, layer, start, b):
+            lo, hi = span(k, start, b)
+            g_asm[...] = jnp.where((row >= lo) & (row < hi),
+                                   view(srcs[k].buf), g_asm[...])
+        each_run(i, fill)
+        update(g_asm[...])
+
+
+def _fold_leaves(m_parts, v_parts, g, mk, vk, *, beta1, beta2, scale,
+                 decay, grad_dtype, grad_scale, guard, interpret):
+    """arena_fold with its gradient gathered from a micro-batch's gradient
+    sources (core/arena.py::GradLeaves): the (rows, LANES) slab is never
+    written. One pallas_call over the arena's row blocks, m and v aliased
+    in place, the same codec update as the slab fold: m and v come out
+    bitwise equal to packing the tree and folding the slab."""
+    from repro.core.arena import sublane_tile
+    from repro.sharding.ctx import kernel_mesh
+    plan = g.plan
+    if guard is not None or grad_scale is not None:
+        raise ValueError("the gathered fold has no finite guard or fp8 "
+                         "wire: fold a packed slab for those")
+    if kernel_mesh() is not None:
+        raise ValueError("the gathered fold does not split rows over a "
+                         "kernel mesh: fold a packed slab there")
+    if grad_dtype is not None and jnp.dtype(grad_dtype) != plan.wire:
+        raise TypeError(f"gradient sources on a {plan.wire} wire != "
+                        f"declared grad_dtype {jnp.dtype(grad_dtype)}")
+    rows = plan.layout.rows
+    _check_parts(m_parts, mk, rows)
+    _check_parts(v_parts, vk, rows)
+    block = min(BLOCK_ROWS, rows)
+    assert rows % block == 0, (rows, block)
+    assert all(block % sublane_tile(d) == 0 for d, _ in plan.bufs), block
+    nm, nv = len(m_parts), len(v_parts)
+    _, body_scale, scalars_fn, _ = _fold_guard_setup(None, scale, None)
+    table, offs = _leaf_tables(plan, block)
+    state = _col_specs(mk.cols, block, sliced=False) \
+        + _col_specs(vk.cols, block, sliced=False)
+    scratch = [pltpu.VMEM((2, block + sublane_tile(d), LANES), d)
+               for d, _ in plan.bufs]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,                   # (run table, scalar vec)
+        grid=(rows // block,),
+        in_specs=state + [pl.BlockSpec(memory_space=pl.ANY)] * len(g.arrays),
+        out_specs=state,
+        scratch_shapes=scratch + [pltpu.VMEM((block, LANES), jnp.float32),
+                                  pltpu.SemaphoreType.DMA((2,))])
+    call = pl.pallas_call(
+        functools.partial(_leaf_fold_body, mk=mk, vk=vk, beta1=beta1,
+                          beta2=beta2, scale=body_scale, nm=nm, nv=nv,
+                          plan=plan, block=block, offs=offs),
+        grid_spec=grid_spec,
+        out_shape=_out_shapes(m_parts + v_parts),
+        input_output_aliases={2 + k: k for k in range(nm + nv)},
+        # blocks run in order: block i prefetches block i+1's gradient
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=64 * 2 ** 20),
+        interpret=_interpret() if interpret is None else interpret,
+        name="arena_fold",
+    )
+    return call(jnp.asarray(table), scalars_fn(decay), *m_parts, *v_parts,
+                *g.arrays)
+
+
 def _slice_fold_body(off_ref, sc_ref, *refs, mk, vk, beta1, beta2, scale,
                      nm, nv, guarded=False, has_gs=False):
     del off_ref                      # consumed by the index maps
@@ -548,6 +780,7 @@ def arena_fold_slice(m, v, g, row_offset, *, beta1: float, beta2: float,
     false), the return gains the flag. Under the bucketed ZeRO-1 schedule
     the caller passes the post-reduce-scatter, psum-agreed flag so all
     shards of the bucket skip or none do."""
+    FOLD_SOURCES["slab"] += 1
     mk, vk = kernel_codec("m", m_codec), kernel_codec("v", v_codec)
     m_parts, m_bare = _as_parts(m)
     v_parts, v_bare = _as_parts(v)

@@ -29,6 +29,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs.base import RunConfig
 from repro.core.accumulation import _zero_constrain, make_train_step
 from repro.data import make_data
+from repro.kernels import fused_step
 from repro.models.model import init_params
 from repro.train import checkpoint as ckpt
 from repro.train import faults as faults_mod
@@ -120,6 +121,8 @@ def train(run: RunConfig, *, lr_schedule=None, log_fn=print,
             # compile ahead of step 1 so no step time includes it; the jit
             # call below reuses this executable
             t0 = time.perf_counter()
+            folds = fused_step.FOLD_SOURCES
+            folds.clear()
             with TraceAnnotation("train.compile"):
                 compiled = jstep.lower(
                     params, opt_state,
@@ -127,6 +130,10 @@ def train(run: RunConfig, *, lr_schedule=None, log_fn=print,
                 ).compile()
             compile_s = time.perf_counter() - t0
             log_fn(f"[train] step compiled in {compile_s:.2f}s")
+            if folds:
+                # which gradient source the step's arena folds read
+                log_fn(f"[train] fold gradient: leaves={folds['leaves']} "
+                       f"slab={folds['slab']}")
         t0 = time.time()
         for i in range(start, run.steps):
             with StepTraceAnnotation("train.step", step_num=i + 1):

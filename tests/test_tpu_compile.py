@@ -104,6 +104,25 @@ def _fold_slice(layout):
     return fn, operands
 
 
+def _fold_gathered(m_codec, v_codec, wire):
+    """The whole-arena fold gathering its gradient from bert_large's
+    gradient tree (arena.gather_sources) instead of a slab."""
+    grads = jax.eval_shape(lambda: init_params(get_config("bert_large"),
+                                               jax.random.key(0)))
+    lay = arena.build_layout(grads)
+
+    def fn(m, v, g):
+        return fs.arena_fold(m, v, arena.gather_sources(g, lay, wire),
+                             beta1=B1, beta2=B2, scale=0.25, decay=(B1, B2),
+                             m_codec=m_codec, v_codec=v_codec,
+                             grad_dtype=wire, interpret=False)
+
+    def operands(rows, sh):
+        return [_cols("m", m_codec, rows, sh), _cols("v", v_codec, rows, sh),
+                jax.tree.map(lambda x: _spec(x.shape, x.dtype, sh), grads)]
+    return fn, operands
+
+
 def _apply_master():
     def fn(p, m, v):
         return fs.arena_apply(p, m, v, lr=1e-3, bc1=0.1, bc2=1e-3,
@@ -121,6 +140,8 @@ KERNELS = {
                                                 jnp.bfloat16, True),
     "fold_guarded_fp8_wire": lambda lay: _fold("fp32", "fp32",
                                                jnp.float8_e4m3fn, True),
+    "fold_gathered_int8_bf16_wire": lambda lay: _fold_gathered(
+        "int8", "int8", jnp.bfloat16),
     "fold_slice": _fold_slice,
     "apply_master_bf16_work": lambda lay: _apply_master(),
 }
@@ -200,3 +221,51 @@ def test_bert_large_zero1_step_compiles_for_v5e_2x2(topo, no_compile_cache,
             params, state, batch).compile()
     assert tuple(o_sh["m"].data.spec) == ("data",)
     _check_fits(compiled)
+
+
+def _computations(hlo: str):
+    """{name: body text} of an HLO module's computations."""
+    comps, name, lines = {}, None, []
+    for line in hlo.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            name, lines = line.split()[1 if line.startswith("ENTRY") else 0], []
+        elif line == "}" and name is not None:
+            comps[name] = "\n".join(lines)
+            name = None
+        elif name is not None:
+            lines.append(line)
+    return comps
+
+
+def test_bert_large_adama_step_writes_no_gradient_slab(one_chip,
+                                                       no_compile_cache,
+                                                       monkeypatch):
+    """The benchmark's bert_large AdamA job (8 micro-batches of 1 x 512):
+    the fold gathers each micro-batch's gradient from the leaves, so the
+    micro-batch scan body holds one Mosaic kernel, the fold, and no other
+    instruction of the (rows, 1024) fp32 slab's shape; and the step needs
+    no more temporaries than the slab path did (v5e compile of the slab
+    path: 7,284,355,584 B)."""
+    step, _, params, state = _bert_large_step(
+        monkeypatch, ["--micro-batches", "8", "--global-batch", "8",
+                      "--seq-len", "512"])
+    place = lambda t: jax.tree.map(
+        lambda s: _spec(s.shape, s.dtype, one_chip), t)
+    batch = {k: _spec((8, 512), jnp.int32, one_chip)
+             for k in ("tokens", "labels")}
+    fs.FOLD_SOURCES.clear()
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+        place(params), place(state), batch).compile()
+    assert fs.FOLD_SOURCES == {"leaves": 1}
+    slab = f"f32[{state['m'].layout.rows},{LANES}]"
+    bodies = [c for c in _computations(compiled.as_text()).values()
+              if "arena_fold" in c and "tpu_custom_call" in c]
+    assert len(bodies) == 1
+    body = bodies[0]
+    assert body.count('custom_call_target="tpu_custom_call"') == 1
+    for line in body.splitlines():
+        lhs, _, rhs = line.partition(" = ")
+        if rhs.startswith(slab):
+            op = rhs.split("} ", 1)[1].split("(", 1)[0]
+            assert op in ("parameter", "get-tuple-element"), line[:160]
+    assert compiled.memory_analysis().temp_size_in_bytes <= 7_284_355_584
