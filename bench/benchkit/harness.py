@@ -259,7 +259,10 @@ def reference_readings(cell, run, seed: int, stream, leaves, *,
     first-moment sums of squares after step 1, change sums of squares
     after step 3) per (leaf, layer). `mode` and `batch_filter` put the
     reference in the program's place for the control and its faults
-    (bench/control.py)."""
+    (bench/control.py). On a cell of several chips the reference's weights,
+    moments, gradients and micro-batches are spread over them
+    (`reference.optim.Spread`), made there from the seed; on one chip it
+    runs on the first device, as the program's weights do."""
     family = importlib.import_module(f"reference.{cell.config['reference']}")
     from reference import optim as ref_optim
     model = run.model
@@ -268,7 +271,14 @@ def reference_readings(cell, run, seed: int, stream, leaves, *,
     shapes = program_shapes(model)
     init, _ = make_init(shapes, model.num_layers)
     key = seed_key(seed)
-    weights = jax.jit(init)(key)
+    devices = jax.devices()[:cell.chips]
+    spread = ref_optim.Spread(devices) if len(devices) > 1 else None
+    if spread is None:
+        weights = jax.jit(init)(key)
+    else:
+        where = jax.tree.unflatten(jax.tree.structure(shapes), [
+            spread.leaf(lf.shape, lf.stacked) for lf in leaves])
+        weights = jax.jit(init, out_shardings=where)(key)
     batches = [jax.tree.map(jnp.asarray, stream.batch(i))
                for i in range(CHECKED_STEPS)]
     out = {}
@@ -293,7 +303,7 @@ def reference_readings(cell, run, seed: int, stream, leaves, *,
             leaf_groups=cell.traffic.get("reference_leaf_groups", 1),
             stacked=[lf.stacked for lf in leaves],
             m_codec=state["m_codec"], v_codec=state["state_codec"],
-            wire=state["grad_dtype"])
+            wire=state["grad_dtype"], spread=spread)
     return losses, out["m"], out["change"]
 
 
@@ -378,6 +388,8 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
     peak = memory_peak(devices)
     hbm = step_hbm_bytes(info.compiled)
     gc.collect()
+    held = max((d.memory_stats() or {}).get("bytes_in_use", 0)
+               for d in devices)
     t_ref = time.perf_counter()
     ref = reference_readings(cell, run, seed, stream, leaves)
     ref_s = time.perf_counter() - t_ref
@@ -416,7 +428,8 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
                        "compile_s": info.compile_s,
                        "step_hbm_bytes": hbm,
                        "window_s": info.window_s,
-                       "setup_s": info.setup_s, "reference_s": ref_s}
+                       "setup_s": info.setup_s, "reference_s": ref_s,
+                       "held_before_reference_bytes": held}
     # the numbers compared, each beside its limit: the line's last key
     result["checks"] = {k: {"value": numbers[k],
                             "limit": cell.limits[k]["limit"]}

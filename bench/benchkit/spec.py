@@ -92,8 +92,9 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
 def build_run(cell: Cell, seed: int, *, reduced: bool = False):
     """The RunConfig `launch/train.py::build_run` makes from the cell's
     arguments, with the configuration's and traffic mix's overrides
-    (`model_overrides`, `run_overrides`) applied, and the ModelConfig.
-    `reduced` runs the same cell at the CPU-scale widths of the family
+    (`model_overrides`, `run_overrides`) applied, and the ModelConfig. A
+    nested override (`"moe": {"n_experts": 8}`) replaces those keys of the
+    nested block. `reduced` runs the same cell at the CPU-scale widths of the family
     (tests only)."""
     from repro.launch.train import build_run as program_build_run
     from repro.launch.train import parse_args
@@ -102,8 +103,12 @@ def build_run(cell: Cell, seed: int, *, reduced: bool = False):
         argv.append("--reduced")
     run, _ = program_build_run(parse_args(argv))
     model = run.model
-    if cell.config.get("model_overrides"):
-        model = dataclasses.replace(model, **cell.config["model_overrides"])
+    over = dict(cell.config.get("model_overrides", {}))
+    for key, value in over.items():
+        if isinstance(value, dict):        # a nested block, such as `moe`
+            over[key] = dataclasses.replace(getattr(model, key), **value)
+    if over:
+        model = dataclasses.replace(model, **over)
     run = dataclasses.replace(run, model=model,
                               **cell.traffic.get("run_overrides", {}))
     if not reduced:
@@ -113,9 +118,24 @@ def build_run(cell: Cell, seed: int, *, reduced: bool = False):
 
 def check_config(cell: Cell, model) -> None:
     """The configuration file holds the sizes as run: every number in its
-    `model` block must equal the program's ModelConfig."""
-    for key, want in cell.config["model"].items():
-        got = getattr(model, key)
-        if got != want:
-            raise SpecError(f"{cell.config_name}: {key}={got!r} in the "
+    `model` block must equal the program's ModelConfig, and every number
+    in a nested block (such as `moe`) the field of the nested config."""
+    _check_block(cell.config_name, cell.config["model"], model, "")
+
+
+def _check_block(config_name: str, block: Dict[str, Any], obj,
+                 prefix: str) -> None:
+    for key, want in block.items():
+        if not hasattr(obj, key):
+            raise SpecError(f"{config_name}: the program has no setting "
+                            f"{prefix}{key}")
+        got = getattr(obj, key)
+        if isinstance(want, dict):
+            if got is None:
+                raise SpecError(f"{config_name}: {prefix}{key} is None in "
+                                f"the program, a block in the "
+                                f"configuration file")
+            _check_block(config_name, want, got, f"{prefix}{key}.")
+        elif got != want:
+            raise SpecError(f"{config_name}: {prefix}{key}={got!r} in the "
                             f"program, {want!r} in the configuration file")

@@ -4,8 +4,10 @@ faults, with the reference put in the program's place.
     python3 bench/control.py --workload <cell> --seeds 11 12 13
 
 For each seed it follows the cell's first three steps with the float32
-reference, then reads the numbers the benchmark compares (bench/benchkit/
-check.py) for:
+reference (spread over the cell's chips, as the benchmark's runs spread
+it), notes the time it took and each chip's memory peak after it (the
+peak of the first seed is the reference's own), then reads the numbers
+the benchmark compares (bench/benchkit/check.py) for:
 
   control          the reference computed with every matrix product in
                    float8 e4m3 (one step below the bf16 the configurations
@@ -44,6 +46,7 @@ def drop_rows(batch, keep):
 
 
 def readings(cell, seed: int, *, reduced: bool = False):
+    import jax
     from benchkit import harness, spec
     from benchkit.data import make_stream
     from benchkit.weights import leaves_of, program_shapes
@@ -52,8 +55,13 @@ def readings(cell, seed: int, *, reduced: bool = False):
     leaves = leaves_of(program_shapes(run.model))
     t = cell.traffic
     gb, n = t["global_batch"], t["micro_batches"]
+    t0 = time.perf_counter()
     ref = harness.reference_readings(cell, run, seed, stream, leaves)
-    out = {"seed": seed}
+    # the reference alone has run in this process so far: the peak is its
+    out = {"seed": seed, "reference_s": time.perf_counter() - t0,
+           "reference_peak_bytes": [
+               (d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+               for d in jax.devices()[:cell.chips]]}
 
     def numbers(r):
         got = harness.compare(r, ref)

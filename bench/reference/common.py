@@ -13,6 +13,7 @@ bfloat16 compute the configurations state, and the comparison that decides
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
 
@@ -112,10 +113,32 @@ def cross_entropy(logits, labels):
     return nll.sum() / jnp.maximum(valid.sum(), 1)
 
 
+_place_layer = None
+
+
+@contextlib.contextmanager
+def placed_layers(place):
+    """While open, `scan_layers` hands each layer's input and weights to
+    `place(x, layer_weights, n_layers)`, which returns them placed, before
+    the block uses them. A sharding constraint there places their
+    gradients too, since its transpose constrains the cotangent
+    (reference/optim.py::Spread.layer)."""
+    global _place_layer
+    prev, _place_layer = _place_layer, place
+    try:
+        yield
+    finally:
+        _place_layer = prev
+
+
 def scan_layers(block, blocks, x):
     """Apply `block(x, layer_weights)` over the stacked layers, keeping
     only each layer's input for the backward pass."""
+    place, n = _place_layer, jax.tree.leaves(blocks)[0].shape[0]
+
     def body(h, lp):
+        if place is not None:
+            h, lp = place(h, lp, n)
         return block(h, lp), None
     x, _ = jax.lax.scan(jax.checkpoint(body), x, blocks)
     return x

@@ -16,13 +16,23 @@ a stacked leaf; the last row zero-padded), scale = row max |x| / 127, m
 rounded toward zero and v rounded up to a multiple of the scale. With a
 bf16 gradient wire (`wire` "bf16") each micro-batch's gradient is rounded
 to bf16 before it is folded.
+
+On several chips (`spread`) only the placement changes: the weights, the
+moments and each micro-batch's gradient are split over the chips leaf by
+leaf (`Spread.leaf`), and each micro-batch along its batch axis; the
+compiler partitions the same float32 arithmetic over them.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from reference.common import placed_layers
 
 ENGINES = ("adama", "ga")
 LANES = 1024
@@ -43,6 +53,44 @@ def _unrows(r, x, stacked):
     return r.reshape(lead + (-1,))[..., :n].reshape(x.shape)
 
 
+class Spread:
+    """Where the reference keeps its state on several chips. A leaf of at
+    least MIN_BYTES is split along its first axis that the chips divide,
+    after the layer axis of a stacked leaf (which the layer scan walks), so
+    that each chip holds a run of consecutive elements of each layer and
+    the int8 rows of 1024 of them stay on one chip; a smaller leaf, or one
+    with no such axis, is held whole on every chip. A micro-batch is split
+    along its batch axis."""
+
+    MIN_BYTES = 1 << 20
+
+    def __init__(self, devices):
+        self.chips = len(devices)
+        self.mesh = Mesh(np.array(devices), ("chips",))
+        self.batch = NamedSharding(self.mesh, P("chips"))
+
+    def leaf(self, shape, stacked: bool) -> NamedSharding:
+        """Where a float32 leaf of `shape` lies."""
+        spec = [None] * len(shape)
+        if 4 * int(np.prod(shape)) >= self.MIN_BYTES:
+            axes = [a for a in range(1 if stacked else 0, len(shape))
+                    if shape[a] % self.chips == 0]
+            if axes:
+                spec[axes[0]] = "chips"
+        return NamedSharding(self.mesh, P(*spec))
+
+    def layer(self, x, weights, n_layers: int):
+        """One layer's input and weights inside the layer scan: the input
+        split along its batch axis, each weight where `leaf` puts that
+        layer of the stacked leaf."""
+        def pin(w):
+            spec = self.leaf((n_layers,) + w.shape, True).spec[1:]
+            return jax.lax.with_sharding_constraint(
+                w, NamedSharding(self.mesh, P(*spec)))
+        return (jax.lax.with_sharding_constraint(x, self.batch),
+                jax.tree.map(pin, weights))
+
+
 def int8_round(x, stacked, signed):
     """x as its per-row int8 code decodes: toward zero when `signed` (m),
     up when not (v)."""
@@ -60,7 +108,7 @@ def run_steps(loss_fn, weights, step_batches, *, n_micro: int, engine: str,
               lr: float, beta1: float, beta2: float, eps: float,
               on_step=None, batch_filter=None, leaf_groups: int = 1,
               stacked=None, m_codec: str = "fp32", v_codec: str = "fp32",
-              wire: str = "fp32"):
+              wire: str = "fp32", spread: Optional[Spread] = None):
     """Follow len(step_batches) optimizer steps from `weights`.
 
     `loss_fn(weights, micro_batch)` is the reference model's loss.
@@ -78,6 +126,9 @@ def run_steps(loss_fn, weights, step_batches, *, n_micro: int, engine: str,
 
     `stacked[i]` says whether leaf i (in `jax.tree.flatten` order) is
     stacked over layers; the int8 moments need it for their rows.
+
+    `spread` places the state over several chips (see `Spread`). Without
+    it everything stays where the weights are.
     """
     if engine not in ENGINES:
         raise ValueError(f"unknown engine {engine!r}")
@@ -91,6 +142,17 @@ def run_steps(loss_fn, weights, step_batches, *, n_micro: int, engine: str,
     for codec in (m_codec, v_codec):
         if codec not in ("fp32", "int8"):
             raise ValueError(f"unknown moment codec {codec!r}")
+
+    if spread is None:
+        def pin(xs, idx):
+            return xs
+    else:
+        where = [spread.leaf(x.shape, st) for x, st in zip(flat, stacked)]
+
+        def pin(xs, idx):
+            return [jax.lax.with_sharding_constraint(x, where[i])
+                    for x, i in zip(xs, idx)]
+    every = tuple(range(len(flat)))
 
     def wire_round(g):
         return g.astype(jnp.bfloat16).astype(jnp.float32) \
@@ -108,39 +170,46 @@ def run_steps(loss_fn, weights, step_batches, *, n_micro: int, engine: str,
             full[i] = x
         return loss_fn(jax.tree.unflatten(treedef, full), mb)
 
-    grad = jax.jit(jax.value_and_grad(group_loss), static_argnums=(3,))
+    @functools.partial(jax.jit, static_argnums=(3,))
+    def grad(sub, rest, mb, idx):
+        with placed_layers(None if spread is None else spread.layer):
+            loss, g = jax.value_and_grad(group_loss)(sub, rest, mb, idx)
+        return loss, pin(g, idx)
 
     @functools.partial(jax.jit, donate_argnums=(0,))
     def scale(t, c):
-        return [c * x for x in t]
+        return pin([c * x for x in t], every)
 
     @functools.partial(jax.jit, donate_argnums=(0, 1),
                        static_argnums=(3,))
-    def fold(m, v, g, st):
+    def fold(m, v, g, idx):
         g = [wire_round(x) / n_micro for x in g]
-        return ([m_round(a + (1 - beta1) * b, k)
-                 for a, b, k in zip(m, g, st)],
-                [v_round(a + (1 - beta2) * jnp.square(b), k)
-                 for a, b, k in zip(v, g, st)])
+        st = [stacked[i] for i in idx]
+        return (pin([m_round(a + (1 - beta1) * b, k)
+                     for a, b, k in zip(m, g, st)], idx),
+                pin([v_round(a + (1 - beta2) * jnp.square(b), k)
+                     for a, b, k in zip(v, g, st)], idx))
 
     @functools.partial(jax.jit, donate_argnums=(0,))
     def add(acc, g):
-        return [a + b / n_micro for a, b in zip(acc, g)]
+        return pin([a + b / n_micro for a, b in zip(acc, g)], every)
 
     @functools.partial(jax.jit, donate_argnums=(0, 1))
     def ga_moments(m, v, g):
-        return ([beta1 * a + (1 - beta1) * b for a, b in zip(m, g)],
-                [beta2 * a + (1 - beta2) * b * b for a, b in zip(v, g)])
+        return (pin([beta1 * a + (1 - beta1) * b for a, b in zip(m, g)],
+                    every),
+                pin([beta2 * a + (1 - beta2) * b * b for a, b in zip(v, g)],
+                    every))
 
     @functools.partial(jax.jit, donate_argnums=(0,))
     def update(w, m, v, t):
         bc1 = 1 - beta1 ** t
         bc2 = 1 - beta2 ** t
-        return [p - lr * (a / bc1) / (jnp.sqrt(b / bc2) + eps)
-                for p, a, b in zip(w, m, v)]
+        return pin([p - lr * (a / bc1) / (jnp.sqrt(b / bc2) + eps)
+                    for p, a, b in zip(w, m, v)], every)
 
-    zeros = jax.jit(lambda t: [jnp.zeros_like(x) for x in t])
-    w = flat
+    zeros = jax.jit(lambda t: pin([jnp.zeros_like(x) for x in t], every))
+    w = flat if spread is None else jax.device_put(flat, where)
     m, v = zeros(w), zeros(w)
     losses = []
     for s, batch in enumerate(step_batches):
@@ -150,6 +219,8 @@ def run_steps(loss_fn, weights, step_batches, *, n_micro: int, engine: str,
         per = rows // n_micro
         micro = [jax.tree.map(lambda x: x[i * per:(i + 1) * per], batch)
                  for i in range(n_micro)]
+        if spread is not None:
+            micro = [jax.device_put(mb, spread.batch) for mb in micro]
         lsum = 0.0
         if engine == "adama":
             m, v = scale(m, beta1), scale(v, beta2)
@@ -157,7 +228,7 @@ def run_steps(loss_fn, weights, step_batches, *, n_micro: int, engine: str,
                 for k, idx in enumerate(groups):
                     l, g = grad([w[i] for i in idx], w, mb, idx)
                     mg, vg = fold([m[i] for i in idx], [v[i] for i in idx],
-                                  g, tuple(stacked[i] for i in idx))
+                                  g, idx)
                     for i, a, b in zip(idx, mg, vg):
                         m[i], v[i] = a, b
                     del g
